@@ -52,6 +52,8 @@ def main() -> None:
         raise SystemExit(
             f"--only {args.only!r} matches no module; --list shows "
             f"valid names (exact, with or without the bench_ prefix)")
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     failures = []
     for mod_name in mods:

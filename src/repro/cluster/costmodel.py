@@ -28,6 +28,20 @@ CHIPS: Dict[str, ChipSpec] = {
     "tpu-v5e": ChipSpec("tpu-v5e", 197e12, 819e9, 16e9),
 }
 
+#: ``jax.Device.device_kind`` -> CHIPS key
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v5 lite": "tpu-v5e",
+}
+
+
+def chip_for_device(device_kind: str) -> ChipSpec:
+    """The cost model of the device JAX runs on; a kind with no entry
+    is an error, never a stand-in."""
+    if device_kind not in DEVICE_KINDS:
+        raise ValueError(f"no cost model for device kind {device_kind!r}; "
+                         f"known: {sorted(DEVICE_KINDS)}")
+    return CHIPS[DEVICE_KINDS[device_kind]]
+
 
 @dataclasses.dataclass
 class EngineCostModel:

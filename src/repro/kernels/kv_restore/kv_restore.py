@@ -11,8 +11,6 @@ tens-of-MB range (Fig. 24) instead of chunk-sized buffers.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -25,7 +23,9 @@ def _kernel(safe_ref, orig_ref, q_ref, scale_ref, pages_in_ref,
             pages_out_ref):
     i = pl.program_id(0)
     q = q_ref[...]  # [1, H, D] uint8
-    deq = (q.astype(jnp.float32) - QOFF) * scale_ref[...][None, :, None]
+    # Mosaic has no uint8 -> float32 cast; widen through int32 (exact)
+    deq = (q.astype(jnp.int32).astype(jnp.float32) - QOFF) \
+        * scale_ref[...][None, :, None]
     # dropped tokens (original slot < 0) keep the old page row
     keep = orig_ref[i] >= 0
     old = pages_in_ref[...]
@@ -33,7 +33,7 @@ def _kernel(safe_ref, orig_ref, q_ref, scale_ref, pages_in_ref,
 
 
 def kv_restore_pallas(pages, q_tokens, scales, slots, *,
-                      interpret: bool = True):
+                      interpret: bool):
     """pages [R, H, D]; q_tokens [n, H, D] u8; scales [H]; slots [n] i32."""
     n, H, D = q_tokens.shape
     slots = slots.astype(jnp.int32)

@@ -1,17 +1,15 @@
 """Jitted public wrapper for the fused KV restoration op."""
 from __future__ import annotations
 
-import functools
-
 import jax
 
+from repro import kernels
 from repro.kernels.kv_restore.kv_restore import kv_restore_pallas
-from repro.kernels.kv_restore.ref import kv_restore_ref
+
+_restore = jax.jit(kv_restore_pallas, static_argnames=("interpret",))
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def kv_restore(pages, q_tokens, scales, slots, *, use_kernel: bool = True,
-               interpret: bool = True):
+def kv_restore(pages, q_tokens, scales, slots):
     """Dequantize decoded uint8 KV tokens and scatter them into paged rows.
 
     pages    [R, H, D] float  (paged KV memory rows)
@@ -19,7 +17,5 @@ def kv_restore(pages, q_tokens, scales, slots, *, use_kernel: bool = True,
     scales   [H] float32      (per-head dequant scales)
     slots    [n] int32        (destination rows; -1 drops the token)
     """
-    if use_kernel:
-        return kv_restore_pallas(pages, q_tokens, scales, slots,
-                                 interpret=interpret)
-    return kv_restore_ref(pages, q_tokens, scales, slots)
+    return _restore(pages, q_tokens, scales, slots,
+                    interpret=kernels.interpret_mode())

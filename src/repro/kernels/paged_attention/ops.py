@@ -1,19 +1,17 @@
 """Jitted public wrapper for paged GQA decode attention."""
 from __future__ import annotations
 
-import functools
-
 import jax
 
+from repro import kernels
 from repro.kernels.paged_attention.paged_attention import (
     paged_attention_pallas,
 )
-from repro.kernels.paged_attention.ref import paged_attention_ref
+
+_attend = jax.jit(paged_attention_pallas, static_argnames=("interpret",))
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def paged_attention(q, k_pages, v_pages, block_tables, context_lens, *,
-                    use_kernel: bool = True, interpret: bool = True):
+def paged_attention(q, k_pages, v_pages, block_tables, context_lens):
     """Decode-time attention of one query token per sequence over a paged
     KV cache.
 
@@ -22,8 +20,5 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens, *,
     block_tables [B, pages_per_seq] int32 (physical page per logical page)
     context_lens [B] int32
     """
-    if use_kernel:
-        return paged_attention_pallas(q, k_pages, v_pages, block_tables,
-                                      context_lens, interpret=interpret)
-    return paged_attention_ref(q, k_pages, v_pages, block_tables,
-                               context_lens)
+    return _attend(q, k_pages, v_pages, block_tables, context_lens,
+                   interpret=kernels.interpret_mode())

@@ -69,7 +69,7 @@ def _kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, out_ref,
 
 
 def paged_attention_pallas(q, k_pages, v_pages, block_tables, context_lens,
-                           *, interpret: bool = True):
+                           *, interpret: bool):
     B, H, hd = q.shape
     P, ps, K, _ = k_pages.shape
     bps = block_tables.shape[1]

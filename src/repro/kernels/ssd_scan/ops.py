@@ -6,21 +6,22 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.ssd_scan.ref import ssd_scan_ref
+from repro import kernels
 from repro.kernels.ssd_scan.ssd_scan import ssd_scan_pallas
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("chunk", "use_kernel", "interpret"))
-def ssd_scan(xdt, a_log, Bm, Cm, *, chunk: int = 128,
-             use_kernel: bool = True, interpret: bool = True):
+def ssd_scan(xdt, a_log, Bm, Cm, *, chunk: int = 128):
     """Mamba2 chunked SSD scan.
 
     xdt [b,s,nh,hd] (x pre-multiplied by dt), a_log [b,s,nh] (dt*A),
     Bm/Cm [b,s,G,S]. Returns (y [b,s,nh,hd] f32, final_state [b,nh,hd,S]).
     """
-    if not use_kernel:
-        return ssd_scan_ref(xdt, a_log, Bm, Cm, chunk=chunk)
+    return _scan(xdt, a_log, Bm, Cm, chunk=chunk,
+                 interpret=kernels.interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _scan(xdt, a_log, Bm, Cm, *, chunk: int, interpret: bool):
     b, s = xdt.shape[:2]
     Q = min(chunk, s)
     pad = (-s) % Q

@@ -61,7 +61,7 @@ def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, st_ref, state_scr,
 
 
 def ssd_scan_pallas(xdt, a_log, Bm, Cm, *, chunk: int = 128,
-                    interpret: bool = True):
+                    interpret: bool):
     """Shapes as ssd_scan_ref; s must be a multiple of `chunk` (the ops
     wrapper pads). G must divide nh (B/C broadcast per head group)."""
     b, s, nh, hd = xdt.shape

@@ -1,30 +1,22 @@
 """Jitted public wrappers for the token-delta transform."""
 from __future__ import annotations
 
-import functools
-
 import jax
 
-from repro.kernels.token_delta.ref import (
-    token_delta_decode_frame_ref, token_delta_encode_ref,
-)
+from repro import kernels
 from repro.kernels.token_delta.token_delta import (
     token_delta_decode_frame_pallas, token_delta_encode_pallas,
 )
 
-
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def token_delta_encode(video, *, use_kernel: bool = True,
-                       interpret: bool = True):
-    if use_kernel:
-        return token_delta_encode_pallas(video, interpret=interpret)
-    return token_delta_encode_ref(video)
+_encode = jax.jit(token_delta_encode_pallas, static_argnames=("interpret",))
+_decode_frame = jax.jit(token_delta_decode_frame_pallas,
+                        static_argnames=("interpret",))
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def token_delta_decode_frame(prev_frame, zres, *, use_kernel: bool = True,
-                             interpret: bool = True):
-    if use_kernel:
-        return token_delta_decode_frame_pallas(prev_frame, zres,
-                                               interpret=interpret)
-    return token_delta_decode_frame_ref(prev_frame, zres)
+def token_delta_encode(video):
+    return _encode(video, interpret=kernels.interpret_mode())
+
+
+def token_delta_decode_frame(prev_frame, zres):
+    return _decode_frame(prev_frame, zres,
+                         interpret=kernels.interpret_mode())

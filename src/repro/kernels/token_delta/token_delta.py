@@ -37,7 +37,7 @@ def _encode_kernel(cur_ref, prev_ref, out_ref):
 
 
 def token_delta_encode_pallas(video, *, block=(8, 128),
-                              interpret: bool = True):
+                              interpret: bool):
     """video [F, H, W] uint8 -> zigzag residuals [F, H, W] uint8."""
     F, H, W = video.shape
     bh = min(block[0], H)
@@ -64,7 +64,7 @@ def _decode_kernel(prev_ref, zres_ref, out_ref):
 
 
 def token_delta_decode_frame_pallas(prev_frame, zres, *, block=(8, 128),
-                                    interpret: bool = True):
+                                    interpret: bool):
     """prev [H, W] u8, zres [H, W] u8 -> reconstructed frame u8."""
     H, W = zres.shape
     bh = min(block[0], H)

@@ -23,7 +23,13 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh over however many host devices exist (tests)."""
+    """Small mesh over the first local devices. Its axes are Auto:
+    arrays carry no sharding in their types, the compiler propagates
+    placements through ordinary ops, and only the Pallas calls are split
+    by hand (``PagedKVCache.shard``)."""
     import jax
+    from jax.sharding import AxisType
     n = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=jax.devices()[:n])

@@ -5,6 +5,11 @@ allocator + per-sequence block tables. Writes happen through
   - ``write_prefill``: bulk scatter of freshly computed K/V, and
   - ``restore_tokens``: the frame-wise fused dequant+scatter kernel
     (repro.kernels.kv_restore), i.e. the paper's Sparse_frame_KV_transfer.
+Decode reads go through ``attend`` (repro.kernels.paged_attention).
+
+``shard(mesh)`` spreads the KV heads over the mesh's "model" axis. Both
+kernels then run under ``shard_map``: each device restores and attends
+over its own heads, and the page arrays are never gathered.
 """
 from __future__ import annotations
 
@@ -14,10 +19,34 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.kernels.kv_restore.ops import kv_restore
+from repro.kernels.paged_attention.ops import paged_attention
 from repro.paged.allocator import PageAllocator
+
+
+# Both use check_vma=False: a pallas_call's out_shape carries no per-axis
+# variance, which shard_map's check would demand.
+def sharded_restore(mesh, axis: str):
+    """``kv_restore`` over rows [R, K, hd] whose heads are split on
+    ``axis``: each device dequantizes and scatters its own heads."""
+    heads = P(None, axis, None)
+    return jax.jit(jax.shard_map(
+        kv_restore, mesh=mesh, in_specs=(heads, heads, P(axis), P()),
+        out_specs=heads, check_vma=False))
+
+
+def sharded_attention(mesh, axis: str):
+    """``paged_attention`` with query heads and KV heads split on
+    ``axis``. Query head h reads KV head h // (H // K), so contiguous
+    query blocks line up with contiguous KV-head blocks."""
+    pages = P(None, None, axis, None)
+    return jax.jit(jax.shard_map(
+        paged_attention, mesh=mesh,
+        in_specs=(P(None, axis, None), pages, pages, P(), P()),
+        out_specs=P(None, axis, None), check_vma=False))
 
 
 @dataclasses.dataclass
@@ -40,6 +69,25 @@ class PagedKVCache:
         self.v_pages = jnp.zeros(shape, dtype)
         self.alloc = PageAllocator(n_pages)
         self.seqs: Dict[int, SeqInfo] = {}
+        self._restore = kv_restore
+        self._attend = paged_attention
+
+    def shard(self, mesh) -> None:
+        """Lay the page arrays out over ``mesh``: KV heads on the
+        "model" axis where they divide it (DEFAULT_RULES), everything
+        else replicated, so tiny models on small meshes stay valid."""
+        from repro.sharding import rules
+        with rules.activate(mesh):
+            spec = rules.logical_to_pspec(
+                ("layers", None, None, "kv_heads", None),
+                self.k_pages.shape, mesh)
+        ns = NamedSharding(mesh, spec)
+        self.k_pages = jax.device_put(self.k_pages, ns)
+        self.v_pages = jax.device_put(self.v_pages, ns)
+        axis = spec[3]
+        if axis is not None:
+            self._restore = sharded_restore(mesh, axis)
+            self._attend = sharded_attention(mesh, axis)
 
     # -- sequence lifecycle ------------------------------------------------
     def add_seq(self, seq_id: int, n_tokens: int) -> SeqInfo:
@@ -113,12 +161,19 @@ class PagedKVCache:
         P = self.n_pages
         pages = self.k_pages if kind == "k" else self.v_pages
         flat = pages[layer].reshape(P * ps, *pages.shape[3:])
-        flat = kv_restore(flat, q_tokens, scales, slots)
+        flat = self._restore(flat, q_tokens, scales, slots)
         updated = pages.at[layer].set(flat.reshape(pages.shape[1:]))
         if kind == "k":
             self.k_pages = updated
         else:
             self.v_pages = updated
+
+    # -- device reads --------------------------------------------------------
+    def attend(self, layer: int, q: jax.Array, block_tables: jax.Array,
+               context_lens: jax.Array) -> jax.Array:
+        """Decode attention of q [B, H, hd] over ``layer``'s pages."""
+        return self._attend(q, self.k_pages[layer], self.v_pages[layer],
+                            block_tables, context_lens)
 
     def gpu_bytes(self) -> int:
         return self.k_pages.nbytes + self.v_pages.nbytes

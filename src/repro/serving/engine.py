@@ -154,6 +154,12 @@ class LiveEngine:
                  # the metrics report
                  on_token: Optional[Callable[[Request, int, float],
                                              None]] = None,
+                 # logits sink for verification: called as
+                 # on_logits(req, logits) with a float32 [V] host copy of
+                 # the logits behind each token; the engine keeps none,
+                 # and copies [B, V] off the device only when it is set
+                 on_logits: Optional[Callable[[Request, np.ndarray],
+                                              None]] = None,
                  # shard the paged cache over a jax device mesh
                  # (launch/mesh.py) and run per-shard fetch/decode/
                  # restore plans as independent flows through the one
@@ -169,7 +175,9 @@ class LiveEngine:
         if prefetch is not None:
             assert isinstance(store, StorageCluster), \
                 "prefetch= needs a multi-node StorageCluster store"
-        self.cache = PagedKVCache(cfg, n_pages, page_size)
+        # pages hold KV in the weights' dtype (bf16 weights -> bf16 KV)
+        self.cache = PagedKVCache(cfg, n_pages, page_size,
+                                  dtype=params["embed"].dtype)
         self.external_dispatch = external_dispatch
         # mesh sharding: page arrays live distributed over the mesh's
         # "model" axis (kv heads); fetch plans split into per-shard
@@ -180,7 +188,7 @@ class LiveEngine:
                 else dict(mesh.shape).get("model", 1)
             assert self.n_shards >= 1
             if mesh is not None:
-                self._shard_cache(mesh)
+                self.cache.shard(mesh)
         #: rid -> (req, shard subplans) for fetches in sharded flight
         self._sharded: Dict[int, Tuple[Request, List[FetchPlan]]] = {}
         #: shadow rid -> real request (restore callbacks remap through it)
@@ -202,6 +210,7 @@ class LiveEngine:
             "WAN options (async fetch, loss=, link_policy=, link_ramp=) " \
             "need a bandwidth trace (virtual clock)"
         self.on_token = on_token
+        self.on_logits = on_logits
         self.cost = cost
         self.ctrl: Optional[FetchController] = None
         if isinstance(store, StorageCluster) and (loss is not None
@@ -258,20 +267,6 @@ class LiveEngine:
         # comes from virtual-clock mode, where this branch never runs
         return self._clock if self.virtual \
             else time.monotonic()  # repro-lint: allow(no-wall-clock)
-
-    # -- mesh-sharded paged cache --------------------------------------------
-    def _shard_cache(self, mesh) -> None:
-        """Lay the paged KV arrays out over ``mesh``: kv heads shard on
-        the "model" axis (DEFAULT_RULES), everything else replicates.
-        Non-divisible dims fall back to replication, so tiny debug
-        models on 1-device meshes stay valid."""
-        from repro.sharding import rules
-        with rules.activate(mesh):
-            ns = rules.named_sharding(
-                ("layers", None, None, "kv_heads", None),
-                self.cache.k_pages.shape, mesh=mesh)
-        self.cache.k_pages = jax.device_put(self.cache.k_pages, ns)
-        self.cache.v_pages = jax.device_put(self.cache.v_pages, ns)
 
     # -- storage-node churn ---------------------------------------------------
     def fail_node(self, node_id: str) -> None:
@@ -509,6 +504,8 @@ class LiveEngine:
         info = self.cache.seqs[req.rid]
         info.context_len = len(tokens)
         nxt = int(jnp.argmax(logits))
+        if self.on_logits is not None:
+            self.on_logits(req, np.asarray(logits, np.float32))
         self.outputs[req.rid].append(nxt)
         req.tokens_out = 1
         req.t_first_token = self.now()
@@ -617,12 +614,16 @@ class LiveEngine:
             logits = paged_model.decode_paged(
                 self.params, self.cfg, toks, positions, self.cache, seq_ids)
             nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            lg = None if self.on_logits is None \
+                else np.asarray(logits, np.float32)
             if self.virtual:
                 ctx = float(np.mean([len(self.prompts[r.rid]) + r.tokens_out
                                      for r in active]))
                 self._clock += self.cost.decode_step_time(len(active), ctx)
             tnow = self.now()
             for i, req in enumerate(active):
+                if lg is not None:
+                    self.on_logits(req, lg[i])
                 self.outputs[req.rid].append(int(nxt[i]))
                 req.tokens_out += 1
                 req.token_times.append(tnow)

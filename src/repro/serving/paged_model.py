@@ -15,7 +15,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.kernels.paged_attention.ops import paged_attention
 from repro.models import mlp as mlp_mod
 from repro.models import moe as moe_mod
 from repro.models.common import apply_rope, rms_norm
@@ -112,8 +111,7 @@ def decode_paged(params, cfg: ModelConfig, tokens: jax.Array,
         for bi, sid in enumerate(seq_ids):
             cache.write_decode_token(i, sid, int(positions[bi]),
                                      k[bi, 0], v[bi, 0])
-        out = paged_attention(q[:, 0], cache.k_pages[i], cache.v_pages[i],
-                              bt, context_lens)
+        out = cache.attend(i, q[:, 0], bt, context_lens)
         x = x + jnp.einsum("bhk,hkd->bd", out, lp["attn"]["wo"])[:, None]
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
         x = x + _mlp_out(lp, h2, cfg)

@@ -1,5 +1,5 @@
-"""Per-kernel validation: Pallas (interpret=True) vs pure-jnp ref oracle,
-with hypothesis shape/dtype sweeps."""
+"""Per-kernel validation: Pallas (interpreted on this backend) vs pure-jnp
+ref oracle, with hypothesis shape/dtype sweeps."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,11 +8,15 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels.kv_restore.ops import kv_restore
+from repro.kernels.kv_restore.ref import kv_restore_ref
 from repro.kernels.paged_attention.ops import paged_attention
+from repro.kernels.paged_attention.ref import paged_attention_ref
 from repro.kernels.ssd_scan.ops import ssd_scan
+from repro.kernels.ssd_scan.ref import ssd_scan_ref
 from repro.kernels.token_delta.ops import (
     token_delta_decode_frame, token_delta_encode,
 )
+from repro.kernels.token_delta.ref import token_delta_encode_ref
 from repro.core.prediction import ZIGZAG, UNZIGZAG
 
 
@@ -36,8 +40,8 @@ def test_kv_restore_matches_ref(n, hd_shape, dtype, seed):
     if n > 1 and seed % 2:
         slots[-1] = -1
     slots = jnp.asarray(slots, jnp.int32)
-    a = kv_restore(pages, q, scales, slots, use_kernel=True)
-    b = kv_restore(pages, q, scales, slots, use_kernel=False)
+    a = kv_restore(pages, q, scales, slots)
+    b = kv_restore_ref(pages, q, scales, slots)
     np.testing.assert_allclose(np.asarray(a, np.float32),
                                np.asarray(b, np.float32), rtol=1e-5,
                                atol=1e-5)
@@ -60,8 +64,8 @@ def test_paged_attention_matches_ref(hkd, ps, seed):
     vp = jnp.asarray(rng.standard_normal((P, ps, K, hd)), jnp.float32)
     bt = jnp.asarray(rng.integers(0, P, (B, bps)), jnp.int32)
     cl = jnp.asarray(rng.integers(1, bps * ps + 1, (B,)), jnp.int32)
-    a = paged_attention(q, kp, vp, bt, cl, use_kernel=True)
-    b = paged_attention(q, kp, vp, bt, cl, use_kernel=False)
+    a = paged_attention(q, kp, vp, bt, cl)
+    b = paged_attention_ref(q, kp, vp, bt, cl)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=3e-5,
                                atol=3e-5)
 
@@ -81,7 +85,7 @@ def test_paged_attention_matches_dense_attention():
     vp = v.reshape(B * bps, ps, K, hd)
     bt = np.arange(P, dtype=np.int32).reshape(B, bps)
     out = paged_attention(jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-                          jnp.asarray(bt), jnp.asarray(cl), use_kernel=True)
+                          jnp.asarray(bt), jnp.asarray(cl))
     # dense reference
     g = H // K
     qg = q.reshape(B, K, g, hd)
@@ -105,8 +109,8 @@ def test_token_delta_encode_matches_ref(F, hw, seed):
     H, W = hw
     rng = np.random.default_rng(seed)
     video = jnp.asarray(rng.integers(0, 256, (F, H, W)), jnp.uint8)
-    a = token_delta_encode(video, use_kernel=True)
-    b = token_delta_encode(video, use_kernel=False)
+    a = token_delta_encode(video)
+    b = token_delta_encode_ref(video)
     assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -116,10 +120,10 @@ def test_token_delta_roundtrip(hw, seed):
     H, W = hw
     rng = np.random.default_rng(seed)
     video = jnp.asarray(rng.integers(0, 256, (4, H, W)), jnp.uint8)
-    zres = token_delta_encode(video, use_kernel=True)
+    zres = token_delta_encode(video)
     prev = jnp.zeros((H, W), jnp.uint8)
     for f in range(4):
-        frame = token_delta_decode_frame(prev, zres[f], use_kernel=True)
+        frame = token_delta_decode_frame(prev, zres[f])
         assert np.array_equal(np.asarray(frame), np.asarray(video[f]))
         prev = frame
 
@@ -147,8 +151,8 @@ def test_ssd_scan_matches_ref(shape, seed):
                         jnp.float32)
     Bm = jnp.asarray(rng.standard_normal((b, s, G, S)) * 0.3, jnp.float32)
     Cm = jnp.asarray(rng.standard_normal((b, s, G, S)) * 0.3, jnp.float32)
-    y_k, st_k = ssd_scan(xdt, a_log, Bm, Cm, chunk=32, use_kernel=True)
-    y_r, st_r = ssd_scan(xdt, a_log, Bm, Cm, chunk=32, use_kernel=False)
+    y_k, st_k = ssd_scan(xdt, a_log, Bm, Cm, chunk=32)
+    y_r, st_r = ssd_scan_ref(xdt, a_log, Bm, Cm, chunk=32)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r), rtol=2e-4,
                                atol=2e-4)
     np.testing.assert_allclose(np.asarray(st_k), np.asarray(st_r),

@@ -817,15 +817,20 @@ def _live_cluster(donor_kv, token_sets, *, cap=None, policy="lru",
 
 def test_live_partial_hit_matches_full_recompute(tiny_cfg, tiny_params,
                                                  donor_kv):
-    """Acceptance: ancestor fetch + tail recompute emits tokens identical
-    to a full recompute of the same prompt."""
+    """Acceptance: ancestor fetch + tail recompute gives the logits of a
+    full recompute of the same prompt, within the int8-KV tolerance."""
     from repro.serving.engine import LiveEngine
+    from repro.serving.verify import (LogitsRecorder, check_streams,
+                                      kv_int8_logit_tolerance)
+    import jax.numpy as jnp
 
     rng = np.random.default_rng(11)
     prompt = rng.integers(0, tiny_cfg.vocab_size, 72)
     # only the 48-token ancestor of the 64-token ask is registered
     cluster = _live_cluster(donor_kv, [prompt[:48]])
-    eng = LiveEngine(tiny_params, tiny_cfg, cluster, resolution="240p")
+    logits, ref_logits = LogitsRecorder(), LogitsRecorder()
+    eng = LiveEngine(tiny_params, tiny_cfg, cluster, resolution="240p",
+                     on_logits=logits)
     req = eng.submit(prompt, reuse_prefix="by-tokens", reuse_tokens=64,
                      max_new_tokens=4)
     eng.run()
@@ -833,10 +838,14 @@ def test_live_partial_hit_matches_full_recompute(tiny_cfg, tiny_params,
     assert req.reuse_tokens == 48 and req.requested_reuse_tokens == 64
     assert cluster.partial_hits == 1
 
-    ref = LiveEngine(tiny_params, tiny_cfg, KVStore(), resolution="240p")
+    ref = LiveEngine(tiny_params, tiny_cfg, KVStore(), resolution="240p",
+                     on_logits=ref_logits)
     ref_req = ref.submit(prompt, max_new_tokens=4)
     ref.run()
-    assert eng.outputs[req.rid] == ref.outputs[ref_req.rid]
+    check_streams(logits[req.rid], eng.outputs[req.rid],
+                  ref_logits[ref_req.rid], ref.outputs[ref_req.rid],
+                  kv_int8_logit_tolerance(tiny_cfg.num_layers, jnp.float32),
+                  "partial hit vs full recompute")
 
 
 def test_live_miss_falls_back_to_full_prefill(tiny_cfg, tiny_params,
