@@ -19,6 +19,7 @@ import dataclasses
 import struct
 from typing import Dict, Iterator, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.core import entropy
@@ -177,21 +178,27 @@ class KVCodec:
         Holds only one reference frame + one residual frame in memory
         (per channel) — the decompress-buffer bound of §3.3.2.
         """
-        info, modes, streams = self._parse(blob)
         from repro.core.prediction import MODE_TEMPORAL
-        fh, fw, _ = info.geom.frame_shape
-        fsz = fh * fw
-        decoders = [(entropy.StreamDecoder(si), entropy.StreamDecoder(sp))
-                    for si, sp in streams]
+        # each span closes before the yield: the consumer's work between
+        # frames is not the codec's
+        with jax.profiler.TraceAnnotation("kvf.codec.frame"):
+            info, modes, streams = self._parse(blob)
+            fh, fw, _ = info.geom.frame_shape
+            fsz = fh * fw
+            decoders = [(entropy.StreamDecoder(si),
+                         entropy.StreamDecoder(sp)) for si, sp in streams]
         prev = None
         for f in range(info.geom.n_frames):
-            zres_f = np.empty((fh, fw, 3), np.uint8)
-            for c in range(3):
-                which = 1 if modes[f, c] == MODE_TEMPORAL else 0
-                zres_f[:, :, c] = decoders[c][which].read(fsz).reshape(fh, fw)
-            frame = predict_decode_frame(zres_f, modes[f], prev)
-            prev = frame
-            toks, qt = unpack_single_frame(frame, info.layout, info.geom, f)
+            with jax.profiler.TraceAnnotation("kvf.codec.frame"):
+                zres_f = np.empty((fh, fw, 3), np.uint8)
+                for c in range(3):
+                    which = 1 if modes[f, c] == MODE_TEMPORAL else 0
+                    zres_f[:, :, c] = decoders[c][which].read(fsz).reshape(
+                        fh, fw)
+                frame = predict_decode_frame(zres_f, modes[f], prev)
+                prev = frame
+                toks, qt = unpack_single_frame(frame, info.layout,
+                                               info.geom, f)
             yield toks, qt[:, :info.n_layers]
 
     def frame_count(self, blob: bytes) -> int:

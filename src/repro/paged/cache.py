@@ -130,19 +130,23 @@ class PagedKVCache:
     def write_prefill(self, layer: int, seq_id: int, k: jax.Array,
                       v: jax.Array, start_pos: int = 0) -> None:
         """k/v [s, K, hd] computed by a prefill pass."""
-        s = k.shape[0]
-        positions = np.arange(start_pos, start_pos + s)
-        slots = jnp.asarray(self.slots_for(seq_id, positions), jnp.int32)
-        ps = self.page_size
-        L, P = self.k_pages.shape[:2]
-        flat_k = self.k_pages[layer].reshape(P * ps, *self.k_pages.shape[3:])
-        flat_v = self.v_pages[layer].reshape(P * ps, *self.v_pages.shape[3:])
-        flat_k = flat_k.at[slots].set(k.astype(flat_k.dtype))
-        flat_v = flat_v.at[slots].set(v.astype(flat_v.dtype))
-        self.k_pages = self.k_pages.at[layer].set(
-            flat_k.reshape(self.k_pages.shape[1:]))
-        self.v_pages = self.v_pages.at[layer].set(
-            flat_v.reshape(self.v_pages.shape[1:]))
+        with jax.profiler.TraceAnnotation("kvf.cache.write"):
+            s = k.shape[0]
+            positions = np.arange(start_pos, start_pos + s)
+            slots = jnp.asarray(self.slots_for(seq_id, positions),
+                                jnp.int32)
+            ps = self.page_size
+            L, P = self.k_pages.shape[:2]
+            flat_k = self.k_pages[layer].reshape(P * ps,
+                                                 *self.k_pages.shape[3:])
+            flat_v = self.v_pages[layer].reshape(P * ps,
+                                                 *self.v_pages.shape[3:])
+            flat_k = flat_k.at[slots].set(k.astype(flat_k.dtype))
+            flat_v = flat_v.at[slots].set(v.astype(flat_v.dtype))
+            self.k_pages = self.k_pages.at[layer].set(
+                flat_k.reshape(self.k_pages.shape[1:]))
+            self.v_pages = self.v_pages.at[layer].set(
+                flat_v.reshape(self.v_pages.shape[1:]))
 
     def write_decode_token(self, layer: int, seq_id: int, pos: int,
                            k: jax.Array, v: jax.Array) -> None:
@@ -155,25 +159,27 @@ class PagedKVCache:
 
         q_tokens [n, K, hd] uint8 (one layer, one frame); scales [K].
         """
-        slots = jnp.asarray(self.slots_for(seq_id, np.asarray(token_ids)),
-                            jnp.int32)
-        ps = self.page_size
-        P = self.n_pages
-        pages = self.k_pages if kind == "k" else self.v_pages
-        flat = pages[layer].reshape(P * ps, *pages.shape[3:])
-        flat = self._restore(flat, q_tokens, scales, slots)
-        updated = pages.at[layer].set(flat.reshape(pages.shape[1:]))
-        if kind == "k":
-            self.k_pages = updated
-        else:
-            self.v_pages = updated
+        with jax.profiler.TraceAnnotation("kvf.cache.restore"):
+            slots = jnp.asarray(
+                self.slots_for(seq_id, np.asarray(token_ids)), jnp.int32)
+            ps = self.page_size
+            P = self.n_pages
+            pages = self.k_pages if kind == "k" else self.v_pages
+            flat = pages[layer].reshape(P * ps, *pages.shape[3:])
+            flat = self._restore(flat, q_tokens, scales, slots)
+            updated = pages.at[layer].set(flat.reshape(pages.shape[1:]))
+            if kind == "k":
+                self.k_pages = updated
+            else:
+                self.v_pages = updated
 
     # -- device reads --------------------------------------------------------
     def attend(self, layer: int, q: jax.Array, block_tables: jax.Array,
                context_lens: jax.Array) -> jax.Array:
         """Decode attention of q [B, H, hd] over ``layer``'s pages."""
-        return self._attend(q, self.k_pages[layer], self.v_pages[layer],
-                            block_tables, context_lens)
+        with jax.profiler.TraceAnnotation("kvf.cache.attend"):
+            return self._attend(q, self.k_pages[layer], self.v_pages[layer],
+                                block_tables, context_lens)
 
     def gpu_bytes(self) -> int:
         return self.k_pages.nbytes + self.v_pages.nbytes

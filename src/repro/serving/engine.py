@@ -81,7 +81,6 @@ class EngineStats:
     restore_buffer_high_water: int = 0
     restored_tokens: int = 0
     fetched_bytes: int = 0
-    steps: int = 0
     prefill_stall_time: float = 0.0  # virtual time spent waiting for KV
 
 
@@ -332,62 +331,63 @@ class LiveEngine:
         *ancestor* manifest (the tail becomes extra suffix prefill — same
         tokens, just more compute), and a **miss** falls back to a plain
         full prefill; fetches route over the serving node's own link."""
-        link = None
-        res_avail = None
-        served_key = None
-        if isinstance(self.store, StorageCluster):
-            tokens = self.prompts[req.rid][:req.reuse_tokens]
-            staged = (self.prefetch.host_lookup_tokens(tokens, self.now())
-                      if self.prefetch is not None else None)
-            if staged is not None:
-                # host-first: the speculatively staged copy serves from
-                # host DRAM over the staging tier's h2d link — the WAN
-                # is off this request's TTFT path entirely
-                req.storage_hit = "host"
-                req.storage_node = "host"
-                req.prefix = staged.key
-                self.prefetch.observe(staged.key, self.now())
-                man = staged.manifest
-                link = self.prefetch.staging.link
+        with jax.profiler.TraceAnnotation("kvf.fetch.start", rid=req.rid):
+            link = None
+            res_avail = None
+            served_key = None
+            if isinstance(self.store, StorageCluster):
+                tokens = self.prompts[req.rid][:req.reuse_tokens]
+                staged = (self.prefetch.host_lookup_tokens(tokens, self.now())
+                          if self.prefetch is not None else None)
+                if staged is not None:
+                    # host-first: the speculatively staged copy serves from
+                    # host DRAM over the staging tier's h2d link — the WAN
+                    # is off this request's TTFT path entirely
+                    req.storage_hit = "host"
+                    req.storage_node = "host"
+                    req.prefix = staged.key
+                    self.prefetch.observe(staged.key, self.now())
+                    man = staged.manifest
+                    link = self.prefetch.staging.link
+                else:
+                    hit = self.store.lookup_tokens(tokens, self.now())
+                    if self.prefetch is not None:
+                        self.prefetch.observe(
+                            hit.entry.key if hit.entry is not None
+                            else hit.missed_key, self.now())
+                    req.storage_hit = hit.kind
+                    if hit.kind == "miss":
+                        req.storage_miss_key = hit.missed_key
+                        self.sched.notify_fetch_miss(req, self.now())
+                        return
+                    req.storage_node = hit.node.node_id
+                    if hit.kind == "partial":
+                        req.requested_reuse_tokens = req.reuse_tokens
+                        req.reuse_tokens = hit.covered_tokens
+                        req.prefix = hit.entry.key  # fetch the ancestor
+                    man = hit.entry.manifest
+                    link = hit.node.link
+                    res_avail = hit.resolutions
+                    served_key = hit.entry.key
             else:
-                hit = self.store.lookup_tokens(tokens, self.now())
-                if self.prefetch is not None:
-                    self.prefetch.observe(
-                        hit.entry.key if hit.entry is not None
-                        else hit.missed_key, self.now())
-                req.storage_hit = hit.kind
-                if hit.kind == "miss":
-                    req.storage_miss_key = hit.missed_key
-                    self.sched.notify_fetch_miss(req, self.now())
-                    return
-                req.storage_node = hit.node.node_id
-                if hit.kind == "partial":
-                    req.requested_reuse_tokens = req.reuse_tokens
-                    req.reuse_tokens = hit.covered_tokens
-                    req.prefix = hit.entry.key  # fetch the ancestor
-                man = hit.entry.manifest
-                link = hit.node.link
-                res_avail = hit.resolutions
-                served_key = hit.entry.key
-        else:
-            man = self.store.lookup(req.prefix)
-        assert man is not None, f"prefix {req.prefix} not registered"
-        plan = build_plan(req.rid, man)
-        self.cache.add_seq(req.rid, req.prompt_len + req.max_new_tokens)
-        if self.ctrl is None:
-            self._run_fetch_wall(req, plan)
-            return
-        if self.n_shards > 1:
-            self._start_sharded(req, plan, link=link,
-                                resolutions=res_avail,
-                                served_key=served_key)
-            return
-        self.ctrl.start(req, plan, self.now(), link=link,
-                        resolutions=res_avail, served_key=served_key)
-        if self.fetch_mode == "sync":
-            # blocking baseline: the engine idles until the (serialized)
-            # pipeline finishes; the virtual clock absorbs the whole fetch
-            self._clock = max(self._clock, self.ctrl.drain(plan))
+                man = self.store.lookup(req.prefix)
+            assert man is not None, f"prefix {req.prefix} not registered"
+            plan = build_plan(req.rid, man)
+            self.cache.add_seq(req.rid, req.prompt_len + req.max_new_tokens)
+            if self.ctrl is None:
+                self._run_fetch_wall(req, plan)
+                return
+            if self.n_shards > 1:
+                self._start_sharded(req, plan, link=link,
+                                    resolutions=res_avail,
+                                    served_key=served_key)
+                return
+            self.ctrl.start(req, plan, self.now(), link=link,
+                            resolutions=res_avail, served_key=served_key)
+            if self.fetch_mode == "sync":
+                # blocking baseline: the engine idles until the (serialized)
+                # pipeline finishes; the virtual clock absorbs the whole fetch
+                self._clock = max(self._clock, self.ctrl.drain(plan))
 
     # -- mesh-sharded fetch: per-shard plans as independent flows -------------
     def _start_sharded(self, req: Request, plan: FetchPlan, *,
@@ -466,22 +466,29 @@ class LiveEngine:
         assert man is not None
         res = pc.resolution or self.resolution
         blob = man.blobs[(pc.ref.chunk_id, res)]
-        self.stats.fetched_bytes += len(blob)
-        lay = IntraLayout(self.cfg.num_kv_heads, self.cfg.head_dim,
-                          *man.layout)
-        codec = KVCodec(self.cfg.num_kv_heads, self.cfg.head_dim, lay)
-        scales_all = man.scales[pc.ref.kind]
-        for toks, qt in codec.iter_decode_frames(blob):
-            buf = qt.nbytes * 2  # residual + reference frame
-            self.stats.restore_buffer_high_water = max(
-                self.stats.restore_buffer_high_water, buf)
-            global_toks = toks + pc.ref.token_start
-            for li, layer in enumerate(pc.ref.layers):
-                self.cache.restore_tokens(
-                    layer, pc.ref.kind, req.rid, global_toks,
-                    jnp.asarray(qt[:, li]),
-                    jnp.asarray(scales_all[layer]))
-            self.stats.restored_tokens += len(toks)
+        with jax.profiler.TraceAnnotation(
+                "kvf.restore.chunk", rid=req.rid, kind=pc.ref.kind,
+                nbytes=len(blob),
+                tokens=pc.ref.token_end - pc.ref.token_start):
+            self.stats.fetched_bytes += len(blob)
+            lay = IntraLayout(self.cfg.num_kv_heads, self.cfg.head_dim,
+                              *man.layout)
+            codec = KVCodec(self.cfg.num_kv_heads, self.cfg.head_dim, lay)
+            scales_all = man.scales[pc.ref.kind]
+            for toks, qt in codec.iter_decode_frames(blob):
+                buf = qt.nbytes * 2  # residual + reference frame
+                self.stats.restore_buffer_high_water = max(
+                    self.stats.restore_buffer_high_water, buf)
+                global_toks = toks + pc.ref.token_start
+                for li, layer in enumerate(pc.ref.layers):
+                    q, scales = qt[:, li], scales_all[layer]
+                    with jax.profiler.TraceAnnotation(
+                            "kvf.restore.h2d",
+                            nbytes=q.nbytes + scales.nbytes):
+                        q, scales = jnp.asarray(q), jnp.asarray(scales)
+                    self.cache.restore_tokens(layer, pc.ref.kind, req.rid,
+                                              global_toks, q, scales)
+                self.stats.restored_tokens += len(toks)
 
     # -- prefill -------------------------------------------------------------
     def _prefill(self, req: Request) -> None:
@@ -494,10 +501,13 @@ class LiveEngine:
         if req.needs_fetch:
             logits = self._suffix_prefill(req, tokens)
         else:
-            logits, kvs = paged_model.prefill_collect_kv(
-                self.params, self.cfg, jnp.asarray(tokens[None]))
-            for layer, (k, v) in enumerate(kvs):
-                self.cache.write_prefill(layer, req.rid, k[0], v[0])
+            with jax.profiler.TraceAnnotation("kvf.prefill.full",
+                                              rid=req.rid,
+                                              tokens=len(tokens)):
+                logits, kvs = paged_model.prefill_collect_kv(
+                    self.params, self.cfg, jnp.asarray(tokens[None]))
+                for layer, (k, v) in enumerate(kvs):
+                    self.cache.write_prefill(layer, req.rid, k[0], v[0])
             logits = logits[0]
             if self.virtual:
                 self._clock += self.cost.prefill_time(len(tokens))
@@ -525,129 +535,141 @@ class LiveEngine:
         time — zero whenever the Appx A.3 condition held at admission."""
         if self.ctrl is None:
             return
-        while req.fetch_done is None and req.layers_ready <= layer:
-            t = self.ctrl.pump_next()
-            if self._sharded:
-                self._check_sharded()
-            if t is None:
-                if req.fetch_done is not None or req.layers_ready > layer:
-                    break  # the final pump completed a sharded fetch
-                raise RuntimeError(
-                    f"rid={req.rid}: layer {layer} KV never arrived")
-            if t > self._clock:
-                self.stats.prefill_stall_time += t - self._clock
-                self._clock = t
+        with jax.profiler.TraceAnnotation("kvf.prefill.await", rid=req.rid,
+                                          layer=layer):
+            while req.fetch_done is None and req.layers_ready <= layer:
+                t = self.ctrl.pump_next()
+                if self._sharded:
+                    self._check_sharded()
+                if t is None:
+                    if req.fetch_done is not None \
+                            or req.layers_ready > layer:
+                        break  # the final pump completed a sharded fetch
+                    raise RuntimeError(
+                        f"rid={req.rid}: layer {layer} KV never arrived")
+                if t > self._clock:
+                    self.stats.prefill_stall_time += t - self._clock
+                    self._clock = t
 
     def _suffix_prefill(self, req: Request, tokens: np.ndarray) -> jax.Array:
         """Prefill only the non-reused suffix, attending over restored
         prefix KV gathered from the paged cache.  Layer k's compute waits
         for layer k's restore event only (layer-wise pipeline)."""
-        cfg = self.cfg
-        n_pre = req.reuse_tokens
-        suffix = jnp.asarray(tokens[None, n_pre:])
-        b, s = suffix.shape
-        positions = jnp.broadcast_to(
-            jnp.arange(n_pre, n_pre + s, dtype=jnp.int32), (b, s))
-        pre_pos = jnp.broadcast_to(jnp.arange(n_pre, dtype=jnp.int32),
-                                   (b, n_pre))
-        info = self.cache.seqs[req.rid]
-        bt = np.asarray(info.block_table)
-        ps = self.cache.page_size
-        rows = bt[np.arange(n_pre) // ps] * ps + np.arange(n_pre) % ps
-        comp = (self.cost.layer_comp_times(s) if self.virtual else
-                [0.0] * cfg.num_layers)
-        x = self.params["embed"][suffix]
-        for i in range(cfg.num_layers):
-            self._await_layer(req, i)
-            lp = paged_model._layer_params(self.params, cfg, i)
-            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            q, k, v = paged_model._qkv(lp["attn"], h, cfg, positions)
-            self.cache.write_prefill(i, req.rid, k[0], v[0],
-                                     start_pos=n_pre)
-            P = self.cache.n_pages
-            pk = self.cache.k_pages[i].reshape(P * ps, cfg.num_kv_heads,
-                                               cfg.head_dim)[rows][None]
-            pv = self.cache.v_pages[i].reshape(P * ps, cfg.num_kv_heads,
-                                               cfg.head_dim)[rows][None]
-            k_all = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
-            v_all = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
-            kpos = jnp.concatenate([pre_pos, positions], axis=1)
-            out = attend(q, k_all, v_all, positions, kpos, causal=True,
-                         window=cfg.sliding_window)
-            x = x + jnp.einsum("bshk,hkd->bsd", out, lp["attn"]["wo"])
-            h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            x = x + paged_model._mlp_out(lp, h2, cfg)
-            self._clock += comp[i]
-        return lm_logits(self.params, cfg, x[:, -1:, :])[0, 0]
+        with jax.profiler.TraceAnnotation(
+                "kvf.prefill.suffix", rid=req.rid,
+                tokens=len(tokens) - req.reuse_tokens):
+            cfg = self.cfg
+            n_pre = req.reuse_tokens
+            suffix = jnp.asarray(tokens[None, n_pre:])
+            b, s = suffix.shape
+            positions = jnp.broadcast_to(
+                jnp.arange(n_pre, n_pre + s, dtype=jnp.int32), (b, s))
+            pre_pos = jnp.broadcast_to(jnp.arange(n_pre, dtype=jnp.int32),
+                                       (b, n_pre))
+            info = self.cache.seqs[req.rid]
+            bt = np.asarray(info.block_table)
+            ps = self.cache.page_size
+            rows = bt[np.arange(n_pre) // ps] * ps + np.arange(n_pre) % ps
+            comp = (self.cost.layer_comp_times(s) if self.virtual else
+                    [0.0] * cfg.num_layers)
+            x = self.params["embed"][suffix]
+            for i in range(cfg.num_layers):
+                self._await_layer(req, i)
+                lp = paged_model._layer_params(self.params, cfg, i)
+                h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+                q, k, v = paged_model._qkv(lp["attn"], h, cfg, positions)
+                self.cache.write_prefill(i, req.rid, k[0], v[0],
+                                         start_pos=n_pre)
+                P = self.cache.n_pages
+                pk = self.cache.k_pages[i].reshape(P * ps, cfg.num_kv_heads,
+                                                   cfg.head_dim)[rows][None]
+                pv = self.cache.v_pages[i].reshape(P * ps, cfg.num_kv_heads,
+                                                   cfg.head_dim)[rows][None]
+                k_all = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
+                v_all = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
+                kpos = jnp.concatenate([pre_pos, positions], axis=1)
+                out = attend(q, k_all, v_all, positions, kpos, causal=True,
+                             window=cfg.sliding_window)
+                x = x + jnp.einsum("bshk,hkd->bsd", out, lp["attn"]["wo"])
+                h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
+                x = x + paged_model._mlp_out(lp, h2, cfg)
+                self._clock += comp[i]
+            return lm_logits(self.params, cfg, x[:, -1:, :])[0, 0]
 
     # -- main loop ------------------------------------------------------------
     def step(self) -> bool:
         """One engine iteration. Returns False when idle and done."""
-        if self.ctrl is not None:
-            self.ctrl.pump(self.now())
-            if self._sharded:
-                self._check_sharded()
-        now = self.now()
-        self.sched.schedule(now)
-        if not self.external_dispatch:
-            for req in self.sched.take_fetches():
-                self._start_fetch(req)
-                self.sched.schedule(self.now())
-        if self.prefetch is not None:
-            # sglang-style tick: launch speculation for heated prefixes
-            # (deferred while demand fetches hold the source link)
-            self.prefetch.tick(self.now())
-        # newly admitted requests need prefill
-        for req in list(self.sched.running):
-            if req.t_first_token is None:
-                self._prefill(req)
-        # one decode step for every running sequence (continuous batching)
-        active = [r for r in self.sched.running
-                  if r.tokens_out < r.max_new_tokens]
-        if active:
-            seq_ids = [r.rid for r in active]
-            toks = jnp.asarray([self.outputs[r.rid][-1] for r in active],
-                               jnp.int32)
-            positions = jnp.asarray(
-                [len(self.prompts[r.rid]) + r.tokens_out - 1
-                 for r in active], jnp.int32)
-            logits = paged_model.decode_paged(
-                self.params, self.cfg, toks, positions, self.cache, seq_ids)
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
-            lg = None if self.on_logits is None \
-                else np.asarray(logits, np.float32)
-            if self.virtual:
-                ctx = float(np.mean([len(self.prompts[r.rid]) + r.tokens_out
-                                     for r in active]))
-                self._clock += self.cost.decode_step_time(len(active), ctx)
-            tnow = self.now()
-            for i, req in enumerate(active):
-                if lg is not None:
-                    self.on_logits(req, lg[i])
-                self.outputs[req.rid].append(int(nxt[i]))
-                req.tokens_out += 1
-                req.token_times.append(tnow)
-                if self.on_token is not None:
-                    self.on_token(req, int(nxt[i]), tnow)
-        for req in list(self.sched.running):
-            if req.tokens_out >= req.max_new_tokens:
-                self.sched.finish(req, self.now())
-                self.cache.free_seq(req.rid)
-                self.finished.append(req)
-        # engine idle but fetches in flight: jump the virtual clock to the
-        # next pipeline event so waiting requests make progress
-        if (self.virtual and self.ctrl is not None
-                and not self.sched.running and not active):
-            t = self.ctrl.next_event_time()
-            if t is not None:
-                self._clock = max(self._clock, t)
-                self.ctrl.pump(self._clock)
+        with jax.profiler.TraceAnnotation("kvf.step"):
+            if self.ctrl is not None:
+                self.ctrl.pump(self.now())
                 if self._sharded:
                     self._check_sharded()
-                self.sched.schedule(self._clock)
-        self.stats.steps += 1
-        return bool(self.sched.running or self.sched.waiting
-                    or self.sched.waiting_for_kv)
+            now = self.now()
+            self.sched.schedule(now)
+            if not self.external_dispatch:
+                for req in self.sched.take_fetches():
+                    self._start_fetch(req)
+                    self.sched.schedule(self.now())
+            if self.prefetch is not None:
+                # sglang-style tick: launch speculation for heated
+                # prefixes (deferred while demand fetches hold the source
+                # link)
+                self.prefetch.tick(self.now())
+            # newly admitted requests need prefill
+            for req in list(self.sched.running):
+                if req.t_first_token is None:
+                    self._prefill(req)
+            # one decode step for every running sequence (continuous
+            # batching)
+            active = [r for r in self.sched.running
+                      if r.tokens_out < r.max_new_tokens]
+            if active:
+                seq_ids = [r.rid for r in active]
+                toks = jnp.asarray([self.outputs[r.rid][-1] for r in active],
+                                   jnp.int32)
+                positions = jnp.asarray(
+                    [len(self.prompts[r.rid]) + r.tokens_out - 1
+                     for r in active], jnp.int32)
+                with jax.profiler.TraceAnnotation("kvf.decode.step",
+                                                  batch=len(active)):
+                    logits = paged_model.decode_paged(
+                        self.params, self.cfg, toks, positions, self.cache,
+                        seq_ids)
+                    nxt = np.asarray(jnp.argmax(logits, axis=-1))
+                lg = None if self.on_logits is None \
+                    else np.asarray(logits, np.float32)
+                if self.virtual:
+                    ctx = float(np.mean([len(self.prompts[r.rid])
+                                         + r.tokens_out for r in active]))
+                    self._clock += self.cost.decode_step_time(len(active),
+                                                              ctx)
+                tnow = self.now()
+                for i, req in enumerate(active):
+                    if lg is not None:
+                        self.on_logits(req, lg[i])
+                    self.outputs[req.rid].append(int(nxt[i]))
+                    req.tokens_out += 1
+                    req.token_times.append(tnow)
+                    if self.on_token is not None:
+                        self.on_token(req, int(nxt[i]), tnow)
+            for req in list(self.sched.running):
+                if req.tokens_out >= req.max_new_tokens:
+                    self.sched.finish(req, self.now())
+                    self.cache.free_seq(req.rid)
+                    self.finished.append(req)
+            # engine idle but fetches in flight: jump the virtual clock to
+            # the next pipeline event so waiting requests make progress
+            if (self.virtual and self.ctrl is not None
+                    and not self.sched.running and not active):
+                t = self.ctrl.next_event_time()
+                if t is not None:
+                    self._clock = max(self._clock, t)
+                    self.ctrl.pump(self._clock)
+                    if self._sharded:
+                        self._check_sharded()
+                    self.sched.schedule(self._clock)
+            return bool(self.sched.running or self.sched.waiting
+                        or self.sched.waiting_for_kv)
 
     def run(self, max_steps: int = 1000) -> None:
         for _ in range(max_steps):
