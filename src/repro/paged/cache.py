@@ -2,14 +2,17 @@
 
 Device state: k_pages / v_pages [L, P, page_size, K, hd]; host state: the
 allocator + per-sequence block tables. Writes happen through
-  - ``write_prefill``: bulk scatter of freshly computed K/V, and
+  - ``write_prefill``: bulk scatter of freshly computed K/V,
+  - ``write_decode_rows``: a decode step's new rows of one layer, written
+    in place into the donated page arrays, and
   - ``restore_tokens``: the frame-wise fused dequant+scatter kernel
     (repro.kernels.kv_restore), i.e. the paper's Sparse_frame_KV_transfer.
 Decode reads go through ``attend`` (repro.kernels.paged_attention).
 
 ``shard(mesh)`` spreads the KV heads over the mesh's "model" axis. Both
 kernels then run under ``shard_map``: each device restores and attends
-over its own heads, and the page arrays are never gathered.
+over its own heads, and the page arrays are never gathered; the decode
+write hands them back in the layout they came in.
 """
 from __future__ import annotations
 
@@ -49,6 +52,32 @@ def sharded_attention(mesh, axis: str):
         out_specs=P(None, axis, None), check_vma=False))
 
 
+def _write_rows(k_pages, v_pages, layer, block_tables, positions, k, v):
+    """Row ``positions[b]`` of sequence b, found through its block table
+    on the device, takes k[b] / v[b] [K, hd] in ``layer``."""
+    ps = k_pages.shape[2]
+    page = block_tables[jnp.arange(positions.shape[0]), positions // ps]
+    row = positions % ps
+    return (k_pages.at[layer, page, row].set(k.astype(k_pages.dtype)),
+            v_pages.at[layer, page, row].set(v.astype(v_pages.dtype)))
+
+
+def page_writer(sharding=None):
+    """``_write_rows`` compiled with both page arrays donated, so the
+    rows are written in place; ``sharding``, where given, is the page
+    arrays' layout, which the updated arrays keep."""
+    kw = {} if sharding is None else {"out_shardings": (sharding, sharding)}
+    return jax.jit(_write_rows, donate_argnums=(0, 1), **kw)
+
+
+_write_decode = page_writer()
+
+
+@jax.jit
+def _layer_pages(k_pages, v_pages, layer):
+    return k_pages[layer], v_pages[layer]
+
+
 @dataclasses.dataclass
 class SeqInfo:
     seq_id: int
@@ -71,6 +100,7 @@ class PagedKVCache:
         self.seqs: Dict[int, SeqInfo] = {}
         self._restore = kv_restore
         self._attend = paged_attention
+        self._write = _write_decode
 
     def shard(self, mesh) -> None:
         """Lay the page arrays out over ``mesh``: KV heads on the
@@ -84,6 +114,7 @@ class PagedKVCache:
         ns = NamedSharding(mesh, spec)
         self.k_pages = jax.device_put(self.k_pages, ns)
         self.v_pages = jax.device_put(self.v_pages, ns)
+        self._write = page_writer(ns)
         axis = spec[3]
         if axis is not None:
             self._restore = sharded_restore(mesh, axis)
@@ -148,9 +179,16 @@ class PagedKVCache:
             self.v_pages = self.v_pages.at[layer].set(
                 flat_v.reshape(self.v_pages.shape[1:]))
 
-    def write_decode_token(self, layer: int, seq_id: int, pos: int,
-                           k: jax.Array, v: jax.Array) -> None:
-        self.write_prefill(layer, seq_id, k[None], v[None], start_pos=pos)
+    def write_decode_rows(self, layer: int, block_tables: jax.Array,
+                          positions: jax.Array, k: jax.Array,
+                          v: jax.Array) -> None:
+        """One decode step's new rows of ``layer``, one per sequence:
+        k/v [B, K, hd] at ``positions`` [B], with rows found through
+        ``block_tables`` [B, pages] on the device."""
+        with jax.profiler.TraceAnnotation("kvf.cache.write"):
+            self.k_pages, self.v_pages = self._write(
+                self.k_pages, self.v_pages, layer, block_tables, positions,
+                k, v)
 
     def restore_tokens(self, layer: int, kind: str, seq_id: int,
                        token_ids: np.ndarray, q_tokens: jax.Array,
@@ -178,8 +216,8 @@ class PagedKVCache:
                context_lens: jax.Array) -> jax.Array:
         """Decode attention of q [B, H, hd] over ``layer``'s pages."""
         with jax.profiler.TraceAnnotation("kvf.cache.attend"):
-            return self._attend(q, self.k_pages[layer], self.v_pages[layer],
-                                block_tables, context_lens)
+            k, v = _layer_pages(self.k_pages, self.v_pages, layer)
+            return self._attend(q, k, v, block_tables, context_lens)
 
     def gpu_bytes(self) -> int:
         return self.k_pages.nbytes + self.v_pages.nbytes

@@ -147,3 +147,85 @@ def test_head_sharded_kernels_keep_pages_split(op, mesh, monkeypatch,
     text = fn.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" not in text
+
+
+# -- the compiled decode step at Yi-34B widths -------------------------------
+
+DECODE_PAGES = 537  # the benchmark's pool: 4 requests of 134 pages, and one
+#: both page arrays (operands 0 and 1) are the outputs' buffers
+ALIAS = ("input_output_alias={ {0}: (0, {}, may-alias), "
+         "{1}: (1, {}, may-alias) }")
+
+
+def _yi34b_step_args(sharding):
+    """Yi-34B cut to 4 layers, bf16 weights placed by ``sharding``: the
+    config, abstract parameters and the step's operands at ``BATCH``."""
+    import dataclasses
+    import functools
+
+    from repro.models import transformer as tf
+    cfg = dataclasses.replace(get_config("yi-34b"), num_layers=4)
+    shapes = jax.eval_shape(functools.partial(tf.init_params, cfg,
+                                              dtype=jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, sharding), shapes)
+    H, hd = cfg.num_heads, cfg.head_dim
+    ops = {"tokens": _sds((BATCH,), jnp.int32, sharding),
+           "x": _sds((BATCH, 1, cfg.d_model), jnp.bfloat16, sharding),
+           "out": _sds((BATCH, H, hd), jnp.bfloat16, sharding),
+           "bt": _sds((BATCH, -(-CTX // PAGE)), jnp.int32, sharding),
+           "rows": _sds((BATCH, cfg.num_kv_heads, hd), jnp.bfloat16,
+                        sharding)}
+    return cfg, params, ops
+
+
+def _pages(cfg, sharding):
+    return _sds((cfg.num_layers, DECODE_PAGES, PAGE, cfg.num_kv_heads,
+                 cfg.head_dim), jnp.bfloat16, sharding)
+
+
+def test_decode_step_compiles_for_v5e_writing_pages_in_place(
+        one_chip, no_compile_cache):
+    """Every program of `decode_paged` but the Pallas kernel (compiled
+    above) compiles for one v5e at Yi-34B widths, batch 4; the row
+    write's page operands are its outputs' buffers."""
+    from repro.paged import cache as cache_mod
+    from repro.serving import paged_model as pm
+    cfg, params, ops = _yi34b_step_args(one_chip)
+    pages = _pages(cfg, one_chip)
+    lp, idx = pm._layer_ref(params, cfg, 1)
+    assert idx == 1  # the stacked layers: one program for all four
+    toks = ops["tokens"]
+    pm._decode_inputs.lower(params["embed"], toks, toks).compile()
+    pm._attn_in.lower(lp, idx, ops["x"], toks, cfg=cfg).compile()
+    pm._attn_out.lower(lp, idx, ops["x"], ops["out"], cfg=cfg).compile()
+    head = {"final_norm": params["final_norm"],
+            "lm_head": params["lm_head"]}
+    pm._head.lower(head, ops["x"], cfg=cfg).compile()
+    cache_mod._layer_pages.lower(pages, pages, 1).compile()
+    text = cache_mod._write_decode.lower(
+        pages, pages, 1, ops["bt"], toks, ops["rows"],
+        ops["rows"]).compile().as_text()
+    assert ALIAS in text
+
+
+def test_decode_write_keeps_head_sharded_pages_split(mesh, no_compile_cache):
+    """Pages split by KV head over four chips (2 of Yi-34B's 8 a chip):
+    the donated row write and the layer slice hold no all-gather, and
+    both hand the pages back split as they came in."""
+    from repro.paged import cache as cache_mod
+    split = NamedSharding(mesh, P(None, None, None, "model", None))
+    cfg, _, ops = _yi34b_step_args(NamedSharding(mesh, P()))
+    pages = _pages(cfg, split)
+    write = cache_mod.page_writer(split).lower(
+        pages, pages, 1, ops["bt"], ops["tokens"], ops["rows"],
+        ops["rows"]).compile()
+    text = write.as_text()
+    assert "all-gather" not in text and ALIAS in text
+    for s in write.output_shardings:
+        assert s.is_equivalent_to(split, 5)
+    sliced = cache_mod._layer_pages.lower(pages, pages, 1).compile()
+    assert "all-gather" not in sliced.as_text()
+    layer = NamedSharding(mesh, P(None, None, "model", None))
+    for s in sliced.output_shardings:
+        assert s.is_equivalent_to(layer, 4)

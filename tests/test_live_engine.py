@@ -7,6 +7,8 @@ same generations as full prefill (up to the shared int8 quantization step).
 Tiny-model fixtures (tiny_cfg / tiny_params / donor_kv / registered_store)
 come from conftest.py.
 """
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -18,32 +20,120 @@ from repro.serving.engine import LiveEngine
 from repro.paged.cache import PagedKVCache
 
 
-def test_paged_decode_matches_dense_decode(tiny_cfg, tiny_params):
-    """Paged decode path == dense-cache decode path on the same model."""
-    CFG, PARAMS = tiny_cfg, tiny_params
+#: prompt lengths of a decode batch, so the new tokens sit at distinct
+#: positions; at 8 rows a page the first one opens its sequence's third
+#: page
+PROMPT_LENS = (16, 21, 11, 30)
+PAGE = 8
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _prefilled(cfg, params, lens):
+    """A paged cache holding a prefilled random prompt of each length
+    (sequence ids 0..), the prompts, their prefill logits and the next
+    token of each."""
     rng = np.random.default_rng(0)
-    tokens = rng.integers(0, CFG.vocab_size, 24)
-    cache = PagedKVCache(CFG, n_pages=64, page_size=8)
-    cache.add_seq(0, 32)
-    logits_p, kvs = paged_model.prefill_collect_kv(
-        PARAMS, CFG, jnp.asarray(tokens[None]))
-    for layer, (k, v) in enumerate(kvs):
-        cache.write_prefill(layer, 0, k[0], v[0])
-    # dense reference
-    dense_cache = tf.init_cache(CFG, 1, 32)
-    logits_d, dense_cache = tf.prefill(PARAMS, CFG,
-                                       tokens=jnp.asarray(tokens[None]),
-                                       cache=dense_cache)
-    np.testing.assert_allclose(np.asarray(logits_p[0]),
-                               np.asarray(logits_d[0, 0]), rtol=2e-4,
-                               atol=2e-4)
-    nxt = int(jnp.argmax(logits_p[0]))
-    lp = paged_model.decode_paged(PARAMS, CFG, jnp.asarray([nxt]),
-                                  jnp.asarray([24]), cache, [0])
-    ld, _ = tf.decode_step(PARAMS, CFG, jnp.asarray([nxt]), jnp.int32(24),
-                           dense_cache)
-    np.testing.assert_allclose(np.asarray(lp[0]), np.asarray(ld[0]),
-                               rtol=3e-4, atol=3e-4)
+    cache = PagedKVCache(cfg, n_pages=64, page_size=PAGE,
+                         dtype=params["embed"].dtype)
+    prompts, prefill_logits = [], []
+    for sid, n in enumerate(lens):
+        tokens = rng.integers(0, cfg.vocab_size, n)
+        cache.add_seq(sid, n + PAGE)
+        logits, kvs = paged_model.prefill_collect_kv(
+            params, cfg, jnp.asarray(tokens[None]))
+        for layer, (k, v) in enumerate(kvs):
+            cache.write_prefill(layer, sid, k[0], v[0])
+        prompts.append(tokens)
+        prefill_logits.append(np.asarray(logits[0]))
+    nxt = [int(np.argmax(lg)) for lg in prefill_logits]
+    return cache, prompts, prefill_logits, nxt
+
+
+def _decode(cfg, params, cache, nxt, lens):
+    return paged_model.decode_paged(
+        params, cfg, jnp.asarray(nxt, jnp.int32),
+        jnp.asarray(lens, jnp.int32), cache, list(range(len(lens))))
+
+
+@functools.lru_cache(maxsize=None)
+def _model(arch):
+    """A reduced config of ``arch`` and its float32 parameters."""
+    import jax
+    from repro.configs import get_config, reduce_config
+    cfg = reduce_config(get_config(arch))
+    return cfg, tf.init_params(cfg, jax.random.PRNGKey(0))
+
+
+# lwm-7b: the tiny model of the other tests; qwen1.5-110b: q/k/v biases;
+# deepseek-moe-16b: a dense first layer kept apart from the stacked MoE
+# layers, so the step picks weights from both layouts
+@pytest.mark.parametrize("arch,batch", [
+    ("lwm-7b", 1), ("lwm-7b", 2), ("lwm-7b", 3), ("lwm-7b", 4),
+    ("qwen1.5-110b", 2), ("deepseek-moe-16b", 2)])
+def test_paged_decode_matches_dense_decode(arch, batch):
+    """Paged decode path == dense-cache decode path on the same model:
+    each sequence of a paged decode batch against the dense decode of
+    that sequence alone (the dense step takes one scalar position)."""
+    cfg, params = _model(arch)
+    lens = PROMPT_LENS[:batch]
+    cache, prompts, prefill_logits, nxt = _prefilled(cfg, params, lens)
+    lp = np.asarray(_decode(cfg, params, cache, nxt, lens))
+    for b, tokens in enumerate(prompts):
+        dense_cache = tf.init_cache(cfg, 1, 32)
+        logits_d, dense_cache = tf.prefill(params, cfg,
+                                           tokens=jnp.asarray(tokens[None]),
+                                           cache=dense_cache)
+        np.testing.assert_allclose(prefill_logits[b],
+                                   np.asarray(logits_d[0, 0]), rtol=2e-4,
+                                   atol=2e-4)
+        ld, _ = tf.decode_step(params, cfg, jnp.asarray([nxt[b]]),
+                               jnp.int32(lens[b]), dense_cache)
+        np.testing.assert_allclose(lp[b], np.asarray(ld[0]), rtol=3e-4,
+                                   atol=3e-4)
+
+
+def test_second_decode_step_compiles_nothing(tiny_cfg, tiny_params):
+    """The step's programs are keyed on shapes, never on positions, tokens
+    or the layer: the next step of the same batch compiles nothing."""
+    import jax
+    lens = PROMPT_LENS
+    cache, _, _, nxt = _prefilled(tiny_cfg, tiny_params, lens)
+    logits = np.asarray(_decode(tiny_cfg, tiny_params, cache, nxt, lens))
+    compiles = []
+
+    def on_event(name, secs, **kw):
+        if name == COMPILE_EVENT:
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        jax.block_until_ready(_decode(
+            tiny_cfg, tiny_params, cache, list(np.argmax(logits, -1)),
+            [n + 1 for n in lens]))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert compiles == []
+
+
+def test_decode_step_reads_nothing_back_to_the_host(tiny_cfg, tiny_params,
+                                                   monkeypatch):
+    """No device-to-host read in a step, its compiles included. The
+    transfer guard refuses one on an accelerator; on the CPU an array's
+    host copy is no transfer, so a read of any array's value is refused
+    as well."""
+    import jax
+    from jax._src import array
+    lens = PROMPT_LENS
+    cache, _, _, nxt = _prefilled(tiny_cfg, tiny_params, lens)
+
+    def refuse(self):
+        raise AssertionError(f"host read of a {self.shape} array")
+
+    with monkeypatch.context() as m:
+        m.setattr(array.ArrayImpl, "_value", property(refuse))
+        with jax.transfer_guard_device_to_host("disallow"):
+            logits = _decode(tiny_cfg, tiny_params, cache, nxt, lens)
+    assert np.isfinite(np.asarray(logits)).all()
 
 
 @pytest.mark.slow
