@@ -4,8 +4,10 @@
 a configuration (``configs/<name>.json``) and a traffic mix
 (``traffic/<name>.json``); each per-layer metric is a reader of its own
 (``metrics/<name>.py``); a configuration names its plain reference
-(``reference/<name>.py``). Adding any of them takes a new file and a new
-entry, never an edit of an existing file.
+(``reference/<name>.py``), and its published architecture
+(``architectures[0]``) names the module that builds its model
+(``architectures/<name>.py``). Adding any of them takes a new file and a
+new entry, never an edit of an existing file.
 """
 from __future__ import annotations
 
@@ -77,6 +79,35 @@ def metric_reader(name: str, here: pathlib.Path = HERE) -> ModuleType:
 def reference(name: str, here: pathlib.Path = HERE) -> ModuleType:
     """A configuration's plain reference module."""
     return _module("reference", name, here)
+
+
+#: what an architecture module gives, each a function
+ARCHITECTURE = ("model_config", "init_weights", "weight_bytes",
+                "prefill_flops", "decode_flops")
+
+
+def architecture(conf: dict, here: pathlib.Path = HERE) -> ModuleType:
+    """The module that builds the model of configuration ``conf``:
+    ``architectures/<name>.py`` for its published ``architectures[0]``.
+    It gives `ARCHITECTURE`: ``model_config(conf)`` (the program's
+    ``ModelConfig``), ``init_weights(cfg, seed)`` (seeded weights as
+    served, on the device), ``weight_bytes(cfg)``, and the model
+    operations of a served token, ``prefill_flops(cfg, n_pre, s)`` (the
+    first token: ``s`` tokens computed after ``n_pre`` in the cache) and
+    ``decode_flops(cfg, context)`` (a later one)."""
+    name = conf["architectures"][0]
+    try:
+        mod = _module("architectures", name, here)
+    except FileNotFoundError as e:
+        raise FileNotFoundError(
+            f"{conf['name']}: no module for architecture {name!r}; add "
+            f"{here.name}/architectures/{name}.py giving "
+            f"{', '.join(ARCHITECTURE)}") from e
+    missing = [f for f in ARCHITECTURE if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"{here.name}/architectures/{name}.py lacks "
+                             f"{missing}")
+    return mod
 
 
 def metrics_for(bench: dict, workload_name: str, trace: bool) -> List[dict]:

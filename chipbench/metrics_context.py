@@ -1,7 +1,7 @@
 """What a per-layer metric's reader is given: the reduced trace of the
 traced window, the kernel calls seen in it, the served tokens with their
-host times, and the chip's peaks. A reader returns a number, or None
-where it finds nothing to read."""
+host times, the cell's architecture module and the chip's peaks. A
+reader returns a number, or None where it finds nothing to read."""
 from __future__ import annotations
 
 import dataclasses
@@ -14,6 +14,7 @@ class Context:
     window_ns: Optional[Tuple[float, float]]  # the window on the trace's clock
     window_s: float  # the traced window on the host clock
     cfg: object  # the program's ModelConfig of the cell
+    arch: object  # the cell's architecture module (bench.architecture)
     peaks: dict  # chipbench/peaks.json entry of the device kind
     #: per kv_restore call: ((n, K, hd), page itemsize, token itemsize,
     #: scale itemsize)

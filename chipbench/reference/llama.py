@@ -13,7 +13,8 @@ the scale absmax / 127 over the prefix's tokens and the head's dims, and
 rounded half to even. The prefix itself runs as a plain prefill with
 exact K and V; each position after it attends over the dequantized
 prefix and the exact K and V of the positions after it. Nothing else
-is approximated.
+is approximated. Where nothing is stored (``n_pre`` 0) it is the exact
+recompute of the whole prompt.
 
 Weights are the benchmark's own arrays (``chipbench.model``): matrices
 in the layout ``wq [L, d, H, hd]``, ``wk``/``wv [L, d, K, hd]``,

@@ -3,8 +3,10 @@
     python -m chipbench.run --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 It builds the cell's model from its configuration file with seeded
-bfloat16 weights, stores the traffic's documents from seeded donor
-prefills, and serves through `repro.serving.engine.LiveEngine` built as
+bfloat16 weights (by the module of the configuration's architecture,
+``architectures/<name>.py``), stores the traffic's documents from seeded
+donor prefills where the mix reuses them, and serves through
+`repro.serving.engine.LiveEngine` built as
 `repro.launch.serve.serve_live` builds it (async fetch at 240p over a
 modelled 16 Gbps link; the modelled link and the virtual clock only
 order the fetch events, no virtual time enters a metric). It warms up
@@ -26,6 +28,7 @@ import dataclasses
 import gc
 import json
 import os
+import pathlib
 import sys
 import tempfile
 import time
@@ -33,7 +36,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from chipbench import bench, model
+from chipbench import bench
 from chipbench import traffic as traffic_mod
 
 #: host clock at import, the fallback origin of ``setup_s``
@@ -66,6 +69,11 @@ def process_age() -> float:
 
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
+
+
+def log_at(msg: str) -> None:
+    """``log`` with the process's age, to place each step of set-up."""
+    log(f"[{process_age():7.2f}s] {msg}")
 
 
 def use_compile_cache() -> str:
@@ -119,7 +127,8 @@ class Sent:
 class Calls:
     """Kernel calls and model work seen while ``on`` (the traced
     window): the shapes the per-layer readers turn into bytes and
-    operations."""
+    operations. Arguments past the recorded ones go to the kernel
+    untouched."""
     on: bool = False
     restore: List[tuple] = dataclasses.field(default_factory=list)
     attend: List[tuple] = dataclasses.field(default_factory=list)
@@ -127,21 +136,23 @@ class Calls:
     def wrap(self, cache) -> None:
         restore, attend = cache._restore, cache._attend
 
-        def rec_restore(pages, q_tokens, scales, slots):
+        def rec_restore(pages, q_tokens, scales, slots, *args, **kw):
             if self.on:
                 self.restore.append((tuple(q_tokens.shape),
                                      np.dtype(pages.dtype).itemsize,
                                      np.dtype(q_tokens.dtype).itemsize,
                                      np.dtype(scales.dtype).itemsize))
-            return restore(pages, q_tokens, scales, slots)
+            return restore(pages, q_tokens, scales, slots, *args, **kw)
 
-        def rec_attend(q, k_pages, v_pages, block_tables, context_lens):
+        def rec_attend(q, k_pages, v_pages, block_tables, context_lens,
+                       *args, **kw):
             if self.on:
                 # context_lens stays on the device until the window ends
                 self.attend.append((tuple(q.shape), tuple(k_pages.shape),
                                     np.dtype(k_pages.dtype).itemsize,
                                     context_lens))
-            return attend(q, k_pages, v_pages, block_tables, context_lens)
+            return attend(q, k_pages, v_pages, block_tables, context_lens,
+                          *args, **kw)
 
         cache._restore, cache._attend = rec_restore, rec_attend
 
@@ -173,10 +184,14 @@ class Clients:
             self.decode_in_step += 1
 
     def send(self, ask: traffic_mod.Ask) -> Sent:
+        """Submit ``ask``, reusing its stored document where the mix
+        reuses, else as a whole prompt to prefill."""
         prompt = self.traffic.prompt(ask)
-        n_pre = len(self.traffic.documents[ask.doc])
+        reuse = self.traffic.reuses
+        n_pre = len(self.traffic.documents[ask.doc]) if reuse else 0
         t = clock()
-        req = self.eng.submit(prompt, reuse_prefix="by-tokens",
+        req = self.eng.submit(prompt,
+                              reuse_prefix="by-tokens" if reuse else None,
                               reuse_tokens=n_pre,
                               max_new_tokens=ask.answer_tokens)
         s = Sent(req.rid, t, prompt, n_pre, ask.answer_tokens)
@@ -218,20 +233,28 @@ class Cell:
     mix: dict
     limits: dict
     per_layer: List[str]
+    #: the benchmark's directory, where its modules are found
+    here: pathlib.Path = bench.HERE
 
 
-def load_cell(workload_name: str) -> Cell:
-    b = bench.benchmark()
+def load_cell(workload_name: str, here: pathlib.Path = bench.HERE) -> Cell:
+    """The cell's files, found by name under ``here``; a mix or an
+    architecture the harness cannot take stops here, before anything
+    is built or timed."""
+    b = bench.benchmark(here.parent)
     wl = bench.workload(b, workload_name)
-    conf = bench.config(wl["config"])
-    mix = bench.traffic(wl["traffic"])
-    limits = bench.limits(workload_name)
+    conf = bench.config(wl["config"], here)
+    mix = bench.traffic(wl["traffic"], here)
+    traffic_mod.check_mix(mix)
+    bench.architecture(conf, here)
+    limits = bench.limits(workload_name, here)
     per_layer = [m["name"] for m in bench.metrics_for(b, workload_name, True)]
-    return Cell(workload_name, conf, mix, limits, per_layer)
+    return Cell(workload_name, conf, mix, limits, per_layer, here)
 
 
 def build(cell: Cell, seed: int, chip=None):
-    """Weights, stored documents and the engine; what set-up makes."""
+    """The architecture module, weights, stored documents (none where
+    the mix reuses nothing) and the engine; what set-up makes."""
     import jax
     from repro.cluster.costmodel import EngineCostModel, chip_for_device
     from repro.cluster.network import BandwidthTrace
@@ -239,26 +262,31 @@ def build(cell: Cell, seed: int, chip=None):
     from repro.serving import paged_model
     from repro.serving.engine import LiveEngine
 
-    cfg = model.model_config(cell.conf)
+    arch = bench.architecture(cell.conf, cell.here)
+    cfg = arch.model_config(cell.conf)
     dev = jax.devices()[0]
     chip = chip if chip is not None else chip_for_device(dev.device_kind)
     t = clock()
-    params = model.init_weights(cfg, seed)
+    params = arch.init_weights(cfg, seed)
     jax.block_until_ready(params)
-    log(f"weights: {cfg.name} {cfg.num_layers} layers, "
-        f"{model.weight_bytes(cfg) / 1e9:.3f} GB bfloat16 "
+    log_at(f"weights: {cfg.name} {cfg.num_layers} layers, "
+        f"{arch.weight_bytes(cfg) / 1e9:.3f} GB bfloat16 "
         f"({clock() - t:.2f}s)")
     tr = traffic_mod.generate(cell.mix, seed, cfg.vocab_size)
     t = clock()
     cluster = StorageCluster([StorageNode("n0")])
-    enc = 0
-    for doc in tr.documents:
-        kv_k, kv_v = paged_model.donor_prefix_kv(params, cfg, doc)
-        entry = cluster.register_prefix(doc, kv_k, kv_v,
-                                        resolutions=("240p",))
-        enc += entry.manifest.total_bytes("240p")
-    log(f"documents: {[len(d) for d in tr.documents]} tokens stored, "
-        f"{enc / 1e6:.2f} MB at 240p ({clock() - t:.2f}s)")
+    if tr.reuses:
+        enc = 0
+        for doc in tr.documents:
+            kv_k, kv_v = paged_model.donor_prefix_kv(params, cfg, doc)
+            entry = cluster.register_prefix(doc, kv_k, kv_v,
+                                            resolutions=("240p",))
+            enc += entry.manifest.total_bytes("240p")
+        log_at(f"documents: {[len(d) for d in tr.documents]} tokens stored, "
+            f"{enc / 1e6:.2f} MB at 240p ({clock() - t:.2f}s)")
+    else:
+        log(f"contexts: {[len(d) for d in tr.documents]} tokens, nothing "
+            "stored: every request is prefilled whole")
     pages_per_req = -(-tr.longest_request // PAGE_SIZE)
     eng = LiveEngine(params, cfg, cluster,
                      n_pages=cell.mix["clients"] * pages_per_req + 1,
@@ -268,16 +296,17 @@ def build(cell: Cell, seed: int, chip=None):
                      adaptive=False, resolution="240p",
                      resolutions=("240p",),
                      cost=EngineCostModel(cfg, chip, 1))
-    log(f"memory held: weights {model.weight_bytes(cfg)} B, page pool "
+    log_at(f"memory held: weights {arch.weight_bytes(cfg)} B, page pool "
         f"{eng.cache.gpu_bytes()} B ({eng.cache.n_pages} pages); the peak "
         "adds the transient copies of the eager path")
-    return cfg, params, tr, cluster, eng
+    return arch, cfg, params, tr, cluster, eng
 
 
 def warm_up(clients: Clients, seed: int) -> None:
     """Touch every program the window runs. The engine serves
     `traffic.warmup_asks` (fetch, host decode, restore, suffix prefill
-    and first token of each document); then `warm_decode` runs one
+    and first token of each document, or the whole prefill of each
+    prompt where nothing is reused); then `warm_decode` runs one
     decode step of every shape the window's batches take."""
     import jax
     with jax.profiler.TraceAnnotation("chipbench.warmup"):
@@ -385,7 +414,7 @@ def describe_window(clients: Clients, t0: float, t_end: float) -> None:
 def sample_for_check(clients: Clients, t0: float, t_end: float, n: int,
                      seed: int) -> List[Sent]:
     """``n`` finished requests of the window drawn from the seed, with a
-    request of the longest document among them."""
+    request of the longest prompt among them."""
     done = sorted((s for s in clients.sent.values()
                    if t0 <= s.t_send < t_end and s.done),
                   key=lambda s: s.rid)
@@ -394,9 +423,9 @@ def sample_for_check(clients: Clients, t0: float, t_end: float, n: int,
     rng = np.random.default_rng(np.random.SeedSequence([seed, 0xc4ec]))
     pick = [done[i] for i in sorted(rng.choice(len(done), min(n, len(done)),
                                                replace=False))]
-    longest = max(s.n_pre for s in done)
-    if all(s.n_pre != longest for s in pick):
-        cands = [s for s in done if s.n_pre == longest]
+    longest = max(len(s.prompt) for s in done)
+    if all(len(s.prompt) != longest for s in pick):
+        cands = [s for s in done if len(s.prompt) == longest]
         pick[0] = cands[int(rng.integers(len(cands)))]
     return pick
 
@@ -405,7 +434,7 @@ def check(cell: Cell, params, sample: List[Sent], pad_to: int,
           control: bool = False) -> Optional[float]:
     """Widest gap between the reference's best logit and its logit of a
     served token, over the sample (None if the sample is empty)."""
-    ref = bench.reference(cell.conf["reference"])
+    ref = bench.reference(cell.conf["reference"], cell.here)
     gaps = ref.served_gaps(params, cell.conf,
                            [(s.prompt, s.n_pre, s.tokens) for s in sample],
                            pad_to, control=control)
@@ -416,6 +445,7 @@ def check(cell: Cell, params, sample: List[Sent], pad_to: int,
 class Served:
     """What one served window leaves for the report and the check."""
     cell: Cell
+    arch: object  # the configuration's architecture module
     cfg: object
     params: dict
     traffic: traffic_mod.Traffic
@@ -458,7 +488,7 @@ def serve(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     jax.monitoring.register_event_duration_secs_listener(on_event)
 
-    cfg, params, tr, cluster, eng = build(cell, seed, chip)
+    arch, cfg, params, tr, cluster, eng = build(cell, seed, chip)
     clients = Clients(eng, tr)
     eng.on_token = clients.on_token
     del eng, cluster  # the clients hold the engine, the engine the store
@@ -467,7 +497,7 @@ def serve(cell: Cell, seed: int, seconds: float, trace: bool, *,
         calls.wrap(clients.eng.cache)
     t = clock()
     warm_up(clients, seed)
-    log(f"warm-up: {clock() - t:.2f}s")
+    log_at(f"warm-up: {clock() - t:.2f}s")
 
     log_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
 
@@ -490,8 +520,8 @@ def serve(cell: Cell, seed: int, seconds: float, trace: bool, *,
     setup_s = process_age()
     t0, t_end, t_stop = drive(clients, seconds,
                               float(cell.mix["grace_seconds"]), start, stop)
-    served = Served(cell, cfg, params, tr, clients, calls, t0, t_end,
-                    t_stop, setup_s, len(compiles), log_dir)
+    served = Served(cell, arch, cfg, params, tr, clients, calls, t0,
+                    t_end, t_stop, setup_s, len(compiles), log_dir)
     log(f"requests: {len(served.sent)} attempted, "
         f"{len(served.sent) - served.failed} finished, {served.failed} "
         f"failed; {served.compiles} compiles in the window")
@@ -511,6 +541,7 @@ def per_layer(served: Served, device_kind: str, peaks=None):
     window_ns = (win[0].start_ns, win[0].end_ns) if win else None
     ctx = Context(trace=ptrace, window_ns=window_ns,
                   window_s=served.t_stop - served.t0, cfg=served.cfg,
+                  arch=served.arch,
                   peaks=peaks or bench.peaks(device_kind),
                   restore_calls=served.calls.restore,
                   attend_calls=[c[:3] + (np.asarray(c[3]),)
@@ -542,8 +573,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     log(f"memory: peak_bytes_in_use {peak} on the fullest chip")
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices), "memory_peak_bytes": peak}
-    units = {m["name"]: m["unit"] for m in
-             bench.benchmark()["end_to_end"] + bench.benchmark()["per_layer"]}
+    spec = bench.benchmark(cell.here.parent)
+    units = {m["name"]: m["unit"]
+             for m in spec["end_to_end"] + spec["per_layer"]}
     breakdown = None
     if trace:
         values, busy, breakdown = per_layer(served, dev.device_kind, peaks)
@@ -597,12 +629,14 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     sys.path.insert(0, str(ROOT / "src"))
+    log_at("started")
     cell = load_cell(args.workload)
     wl = bench.workload(bench.benchmark(), args.workload)
     use_compile_cache()
+    log_at("jax imported")
     devices = chips_or_exit(int(wl["chips"]))
     dev = devices[0]
-    log(f"device: {dev.platform} {dev.device_kind} x{len(devices)}")
+    log_at(f"device: {dev.platform} {dev.device_kind} x{len(devices)}")
     log(f"config: {cell.conf['name']} ({cell.conf['source']}), reduced "
         f"{sorted(cell.conf['reduced'])}")
     import jax

@@ -1,15 +1,16 @@
 """The program's own host spans (``kvf.*``, see docs/architecture.md,
 "Tracing") in the traced window, for the per-layer readers.
 
-`chipbench.trace.load` keeps a host span's name, start and duration but
-not its arguments, so what a reader needs beyond those comes from the
-harness's own records on the same window: the kernel calls in
-``ctx.restore_calls`` (one ``kvf.cache.restore`` span and one
-``kvf.restore.h2d`` span each, in the same order) and the first tokens
-in ``ctx.clients.token_log`` (one ``kvf.prefill.suffix`` or
-``kvf.prefill.full`` span each, in the same order). Every reader returns
-None where the trace holds no such span, as a program without them
-gives.
+`chipbench.trace.load` keeps a host span's name, start and duration,
+and its arguments as strings (``Event.tags``), which a new reader may
+take a new span's shapes from. The readers here take what they need
+beyond names and times from the harness's own records on the same
+window: the kernel calls in ``ctx.restore_calls`` (one
+``kvf.cache.restore`` span and one ``kvf.restore.h2d`` span each, in the
+same order) and the first tokens in ``ctx.clients.token_log`` (one
+``kvf.prefill.suffix`` or ``kvf.prefill.full`` span each, in the same
+order). Every reader returns None where the trace holds no such span,
+as a program without them gives.
 """
 from __future__ import annotations
 

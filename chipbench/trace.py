@@ -3,9 +3,9 @@
 `load` reads the ``.xplane.pb`` that ``jax.profiler`` writes: the device
 operations (planes ``/device:TPU:<n>``, line ``XLA Ops``) and the host's
 spans on the Python thread (the harness's ``TraceAnnotation``s and what
-JAX records around dispatch). All times are nanoseconds on the trace's
-one clock. The reductions below work on plain `Event` lists, so they are
-checked on synthetic traces.
+JAX records around dispatch), each span with its arguments as tags. All
+times are nanoseconds on the trace's one clock. The reductions below
+work on plain `Event` lists, so they are checked on synthetic traces.
 """
 from __future__ import annotations
 
@@ -27,7 +27,8 @@ class Event:
     name: str
     start_ns: float
     dur_ns: float
-    #: the stats that name an op: hlo_module, hlo_op, long_name, tf_op
+    #: a device op's stats that name it (hlo_module, hlo_op, long_name,
+    #: tf_op); a host span's arguments, every one, as strings
     tags: Tuple[Tuple[str, str], ...] = ()
     device: int = 0
 
@@ -61,16 +62,15 @@ class Trace:
 _TAGS = ("hlo_module", "hlo_op", "long_name", "tf_op", "name")
 
 
-def _stats(ev) -> Tuple[Tuple[str, str], ...]:
-    out = []
+def _stats(ev, keep=_TAGS) -> Tuple[Tuple[str, str], ...]:
+    """The event's stats named in ``keep`` (all where it is None), as
+    strings."""
     try:
         items = list(ev.stats)
     except (AttributeError, TypeError):
         return ()
-    for k, v in items:
-        if k in _TAGS:
-            out.append((str(k), str(v)))
-    return tuple(out)
+    return tuple((str(k), str(v)) for k, v in items
+                 if keep is None or k in keep)
 
 
 def find_xplane(log_dir: str) -> str:
@@ -110,7 +110,8 @@ def load(path: str, device_plane: str = DEVICE_PLANE,
                     continue
                 for ev in line.events:
                     host.append(Event(ev.name, float(ev.start_ns),
-                                      float(ev.duration_ns)))
+                                      float(ev.duration_ns),
+                                      _stats(ev, None)))
     return Trace(ops, host, len(devices), modules)
 
 

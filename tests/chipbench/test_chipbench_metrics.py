@@ -10,6 +10,8 @@ from chipbench import trace as tr
 from chipbench.metrics_context import Context
 
 PEAKS = {"bf16_flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
+#: the architecture module of the Yi configurations
+LLAMA = bench.architecture(bench.config("yi-34b-l4"))
 
 
 @dataclasses.dataclass
@@ -38,9 +40,9 @@ class Clients:
 
 def ctx(**kw):
     base = dict(trace=tr.Trace([], [], 1), window_ns=(0.0, 1e9),
-                window_s=1.0, cfg=Cfg(), peaks=PEAKS, restore_calls=[],
-                attend_calls=[], clients=Clients({}, [], []), t0=0.0,
-                t_stop=1.0, compiles=0)
+                window_s=1.0, cfg=Cfg(), arch=LLAMA, peaks=PEAKS,
+                restore_calls=[], attend_calls=[],
+                clients=Clients({}, [], []), t0=0.0, t_stop=1.0, compiles=0)
     base.update(kw)
     return Context(**base)
 
@@ -81,22 +83,55 @@ def test_paged_attention_flops_and_bytes_by_hand():
 
 def test_step_mfu_counts_prefill_and_decode_by_hand():
     m = bench.metric_reader("step_mfu")
+    a = LLAMA
     cfg = Cfg()
     # per token per layer: q,k,v 2*8*(4+4)*2, out 2*4*2*8, mlp 2*3*8*16
     per_layer = 2 * (8 * 8 * 2 + 4 * 2 * 8 + 3 * 8 * 16)
-    assert m.layer_matmul_flops(cfg) == per_layer
-    assert m.attention_flops(cfg, 7) == 4 * 4 * 2 * 7
+    assert a.layer_matmul_flops(cfg) == per_layer
+    assert a.attention_flops(cfg, 7) == 4 * 4 * 2 * 7
     head = 2 * 8 * 10
     # a 2-token suffix over a 5-token prefix: contexts 6 and 7
-    assert m.prefill_flops(cfg, 5, 2) == \
+    assert a.prefill_flops(cfg, 5, 2) == \
         3 * (2 * per_layer + 4 * 4 * 2 * (6 + 7)) + head
-    assert m.decode_flops(cfg, 9) == 3 * (per_layer + 4 * 4 * 2 * 9) + head
+    assert a.decode_flops(cfg, 9) == 3 * (per_layer + 4 * 4 * 2 * 9) + head
     sent = {0: Sent(np.zeros(7, np.int64), 5)}
     log = [(0.1, 0, 0), (0.2, 0, 1), (5.0, 0, 2)]  # the last is outside
     got = m.read(ctx(clients=Clients(sent, log, []), window_s=2.0))
-    want = m.prefill_flops(cfg, 5, 2) + m.decode_flops(cfg, 8)
+    want = a.prefill_flops(cfg, 5, 2) + a.decode_flops(cfg, 8)
     assert got == pytest.approx(100 * want / (2.0 * 100.0))
     assert m.read(ctx()) is None
+
+
+def test_step_mfu_counts_the_whole_prompt_where_nothing_is_reused():
+    m = bench.metric_reader("step_mfu")
+    cfg = Cfg()
+    per_layer = LLAMA.layer_matmul_flops(cfg)
+    # 3 prompt tokens computed from position 0: contexts 1, 2 and 3
+    assert LLAMA.prefill_flops(cfg, 0, 3) == \
+        3 * (3 * per_layer + 4 * 4 * 2 * (1 + 2 + 3)) + 2 * 8 * 10
+    sent = {4: Sent(np.zeros(3, np.int64), 0)}
+    got = m.read(ctx(clients=Clients(sent, [(0.5, 4, 0)], [])))
+    assert got == pytest.approx(
+        100 * LLAMA.prefill_flops(cfg, 0, 3) / (1.0 * 100.0))
+
+
+def test_step_mfu_asks_the_cells_architecture():
+    """The count is the architecture module's: a module of another
+    architecture, given as ``ctx.arch``, sets it."""
+    class Toy:
+        @staticmethod
+        def prefill_flops(cfg, n_pre, s):
+            return 1000 * s + n_pre
+
+        @staticmethod
+        def decode_flops(cfg, context):
+            return 10 * context
+
+    m = bench.metric_reader("step_mfu")
+    sent = {0: Sent(np.zeros(7, np.int64), 5)}
+    log = [(0.1, 0, 0), (0.2, 0, 1)]
+    got = m.read(ctx(clients=Clients(sent, log, []), arch=Toy))
+    assert got == pytest.approx(100 * (2005 + 80) / (1.0 * 100.0))
 
 
 def test_decode_batch_idle_share_and_compiles():

@@ -45,7 +45,7 @@ def ctx(host=(), window=(100.0, 1100.0), restore_calls=(), sent=None,
     """A traced window of 1000 ns on the trace's clock that opened at
     host time ``t0``."""
     return Context(trace=tr.Trace([], list(host), 0), window_ns=window,
-                   window_s=1e-6, cfg=Cfg(), peaks=PEAKS,
+                   window_s=1e-6, cfg=Cfg(), arch=None, peaks=PEAKS,
                    restore_calls=list(restore_calls), attend_calls=[],
                    clients=Clients(sent or {}, list(token_log)), t0=t0,
                    t_stop=t0 + 1e-6, compiles=0)
@@ -88,6 +88,53 @@ def test_suffix_prefill_self_time_leaves_out_its_waits():
     assert read("suffix_prefill_ms", ctx(host)) == \
         pytest.approx((150 + 90) / 2 / 1e6)
     assert read("suffix_prefill_ms", ctx()) is None
+
+
+def test_full_prefill_self_time_leaves_out_its_page_writes():
+    host = [ev("kvf.prefill.full", 100, 400),
+            ev("kvf.cache.write", 300, 50), ev("kvf.cache.write", 400, 60),
+            ev("kvf.prefill.full", 600, 100),
+            # a decode step's write, outside any prefill
+            ev("kvf.cache.write", 800, 20),
+            # a full prefill the window cuts is not counted
+            ev("kvf.prefill.full", 1050, 100)]
+    assert read("full_prefill_ms", ctx(host)) == \
+        pytest.approx((290 + 100) / 2 / 1e6)
+    assert read("full_prefill_ms", ctx()) is None
+    # the restore path's suffix prefills are not full prefills
+    assert read("full_prefill_ms",
+                ctx([ev("kvf.prefill.suffix", 100, 300)])) is None
+
+
+def test_calls_forward_further_kernel_arguments_untouched():
+    """A kernel that takes more than the recorded arguments (a window,
+    a layer kind) is called with all of them, and still recorded."""
+    seen = []
+
+    class Cache:
+        def _restore(self, *a, **k):
+            seen.append(("restore", a[4:], k))
+            return "r"
+
+        def _attend(self, *a, **k):
+            seen.append(("attend", a[5:], k))
+            return "a"
+
+    cache, calls = Cache(), run.Calls(on=True)
+    calls.wrap(cache)
+    pages = np.zeros((2, 4), np.float16)
+    toks = np.zeros((3, 2, 4), np.uint8)
+    assert cache._restore(pages, toks, np.zeros(2, np.float32),
+                          np.zeros(3, np.int32), 7, kind="k") == "r"
+    q = np.zeros((1, 4, 8), np.float16)
+    kp = np.zeros((5, 16, 2, 8), np.float16)
+    assert cache._attend(q, kp, kp, None, np.array([9]), window=512) == "a"
+    assert cache._attend(q, kp, kp, None, np.array([9])) == "a"
+    assert seen == [("restore", (7,), {"kind": "k"}),
+                    ("attend", (), {"window": 512}), ("attend", (), {})]
+    assert calls.restore == [((3, 2, 4), 2, 1, 4)]
+    assert [c[:3] for c in calls.attend] == \
+        [((1, 4, 8), (5, 16, 2, 8), 2)] * 2
 
 
 def test_decode_step_median_and_page_write_share():

@@ -86,3 +86,23 @@ def test_load_reads_host_spans_of_a_trace_recorded_here(tmp_path):
     assert tr.busy_seconds(t) == 0.0
     assert any("plane" in line for line in
                tr.describe(tr.find_xplane(str(tmp_path))))
+
+
+def test_load_keeps_each_host_spans_arguments_as_strings(tmp_path):
+    """A new reader can take a new span's shapes from its own
+    arguments."""
+    import jax
+    import jax.numpy as jnp
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("chipbench.window"):
+        with jax.profiler.TraceAnnotation("kvf.test", rid=7, kind="k",
+                                          tokens=2112):
+            jnp.ones((8, 8)).sum().block_until_ready()
+    jax.profiler.stop_trace()
+    t = tr.load(tr.find_xplane(str(tmp_path)))
+    span, = [e for e in t.host if e.name == "kvf.test"]
+    assert (span.tag("rid"), span.tag("kind"), span.tag("tokens")) == \
+        ("7", "k", "2112")
+    assert span.tag("no_such_argument") == ""
+    win, = [e for e in t.host if e.name == "chipbench.window"]
+    assert win.start_ns <= span.start_ns and span.end_ns <= win.end_ns

@@ -5,8 +5,10 @@ import pytest
 
 from chipbench import bench, traffic
 
+MIXES = ["doc-qa", "doc-qa-fresh"]
 
-@pytest.mark.parametrize("name", ["doc-qa"])
+
+@pytest.mark.parametrize("name", MIXES)
 def test_same_seed_same_requests_other_seed_other_tokens(name):
     mix = bench.traffic(name)
     a = traffic.generate(mix, 2**33 + 1, 64000)
@@ -18,7 +20,7 @@ def test_same_seed_same_requests_other_seed_other_tokens(name):
         == [[(q.doc, q.question.tolist()) for q in cl] for cl in b.asks]
     assert any(not np.array_equal(x, y)
                for x, y in zip(a.documents, c.documents))
-    assert [len(d) for d in a.documents] == mix["documents"] \
+    assert [len(d) for d in a.documents] == traffic.lengths(mix) \
         == [len(d) for d in c.documents]
     assert [[q.doc for q in cl] for cl in a.asks] == \
         [[q.doc for q in cl] for cl in c.asks]
@@ -26,10 +28,10 @@ def test_same_seed_same_requests_other_seed_other_tokens(name):
                for x, y in zip(a.asks[0], c.asks[0]))
 
 
-@pytest.mark.parametrize("name", ["doc-qa"])
+@pytest.mark.parametrize("name", MIXES)
 def test_every_seed_sends_the_same_set_of_sizes(name):
     mix = bench.traffic(name)
-    n_docs = len(mix["documents"])
+    n_docs = len(traffic.lengths(mix))
     for seed in (0, 7, 2**40):
         t = traffic.generate(mix, seed, 64000)
         assert len(t.asks) == mix["clients"]
@@ -41,7 +43,7 @@ def test_every_seed_sends_the_same_set_of_sizes(name):
             assert all(len(q.question) == mix["question_tokens"]
                        and q.answer_tokens == mix["answer_tokens"]
                        for q in cl)
-        assert t.longest_request == max(mix["documents"]) + \
+        assert t.longest_request == max(traffic.lengths(mix)) + \
             mix["question_tokens"] + mix["answer_tokens"]
 
 
@@ -67,3 +69,31 @@ def test_open_loop_and_missing_keys_are_refused():
     del mix["clients"]
     with pytest.raises(ValueError, match="clients"):
         traffic.generate(mix, 1, 100)
+
+
+def test_the_fresh_mix_sends_the_doc_qa_prompts_and_reuses_nothing():
+    """Reuse against recompute on the same requests: one seed gives the
+    two mixes the same prompts, and only doc-qa stores its contexts."""
+    qa, fresh = bench.traffic("doc-qa"), bench.traffic("doc-qa-fresh")
+    a = traffic.generate(qa, 2**33 + 9, 64000)
+    b = traffic.generate(fresh, 2**33 + 9, 64000)
+    assert a.reuses and not b.reuses
+    for ca, cb in zip(a.asks, b.asks):
+        for x, y in zip(ca, cb):
+            np.testing.assert_array_equal(a.prompt(x), b.prompt(y))
+    assert a.request_lengths == b.request_lengths
+
+
+def test_a_mix_gives_its_lengths_as_documents_or_contexts():
+    """The key that gives the lengths says whether they are stored: a mix
+    with neither, or with both, is refused."""
+    fresh = bench.traffic("doc-qa-fresh")
+    assert "documents" not in fresh and not traffic.generate(
+        fresh, 1, 100).reuses
+    with pytest.raises(ValueError, match="exactly one"):
+        traffic.check_mix(dict(fresh, documents=fresh["contexts"]))
+    neither = dict(fresh)
+    del neither["contexts"]
+    with pytest.raises(ValueError, match="exactly one"):
+        traffic.check_mix(neither)
+    assert traffic.generate(dict(neither, documents=[3, 4]), 1, 100).reuses
